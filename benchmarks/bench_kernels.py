@@ -14,13 +14,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro.kernels import ops
+from repro.kernels.fedagg import fedagg
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.prox_sgd import prox_sgd
 from repro.kernels.ref import (
     attention_ref,
     fedagg_ref,
     prox_sgd_ref,
     wkv6_ref,
 )
+from repro.kernels.wkv6 import wkv6
 
 
 def _time(fn, *args, reps=5):
@@ -39,7 +42,8 @@ def run():
     w = jnp.asarray(rng.random(10), jnp.float32)
     rows.append(("fedagg_ref_us", round(_time(jax.jit(fedagg_ref), x, w), 1),
                  "10x47887"))
-    rows.append(("fedagg_pallas_interp_us", round(_time(ops.fedagg_op, x, w), 1),
+    rows.append(("fedagg_pallas_interp_us", round(_time(
+        lambda a, b: fedagg(a, b, interpret=True), x, w), 1),
                  "10x47887"))
     # prox_sgd
     p = jnp.asarray(rng.normal(size=47887), jnp.float32)
@@ -47,9 +51,9 @@ def run():
     ref = jax.jit(lambda a, b, c: prox_sgd_ref(a, b, c, 0.05, 0.1))
     rows.append(("prox_sgd_ref_us", round(_time(ref, p, g, p), 1), "47887"))
     rows.append(("prox_sgd_pallas_interp_us",
-                 round(_time(lambda a, b, c: ops.prox_sgd_op(a, b, c, 0.05,
-                                                             0.1), p, g, p),
-                       1), "47887"))
+                 round(_time(lambda a, b, c: prox_sgd(a, b, c, 0.05, 0.1,
+                                                      interpret=True),
+                             p, g, p), 1), "47887"))
     # flash attention
     q = jnp.asarray(rng.normal(size=(1, 4, 256, 64)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, 2, 256, 64)), jnp.float32)
@@ -57,8 +61,9 @@ def run():
     rows.append(("attn_ref_us", round(_time(refa, q, k, k), 1),
                  "B1H4S256D64"))
     rows.append(("attn_pallas_interp_us",
-                 round(_time(lambda a, b, c: ops.flash_attention_op(
-                     a, b, c, bq=64, bk=64), q, k, k), 1), "B1H4S256D64"))
+                 round(_time(lambda a, b, c: flash_attention(
+                     a, b, c, bq=64, bk=64, interpret=True), q, k, k), 1),
+                 "B1H4S256D64"))
     # wkv6
     r = jnp.asarray(rng.normal(size=(1, 4, 256, 64)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(1, 4, 256, 64)), jnp.float32)
@@ -69,8 +74,8 @@ def run():
     rows.append(("wkv6_ref_us", round(_time(refw, r, r, v, lw, s0), 1),
                  "T256K64"))
     rows.append(("wkv6_pallas_interp_us",
-                 round(_time(lambda *a: ops.wkv6_op(*a), r, r, v, lw, s0),
-                       1), "T256K64"))
+                 round(_time(lambda *a: wkv6(*a, interpret=True),
+                             r, r, v, lw, s0), 1), "T256K64"))
     return rows
 
 

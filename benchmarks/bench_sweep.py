@@ -49,6 +49,7 @@ from benchmarks.common import (     # noqa: E402
     run_scenario,
     run_scenarios_batched,
 )
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 ALG_SUITE = ("fedavg", "fedavg_sched", "fedavg_intracc",
              "fedprox", "fedprox_sched", "fedprox_sched_v2",
@@ -229,6 +230,7 @@ def main(argv=None):
         if unknown:
             ap.error(f"unknown algorithm(s) {unknown}; registered "
                      f"algorithms: {algorithm_names()}")
+    use_compile_cache()
     horizon_s = (args.horizon_days * 86400.0 if args.horizon_days
                  else HORIZON_S)
     if args.trace:

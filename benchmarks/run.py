@@ -26,6 +26,7 @@ import json
 import os
 import sys
 import time
+import traceback
 
 from benchmarks import (
     bench_accuracy,
@@ -40,6 +41,7 @@ from benchmarks import (
 from benchmarks.common import emit
 
 from repro import obs  # noqa: E402  (benchmarks.common puts src/ on path)
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 # Every suite takes (full, execution, link_model, workload, algorithms,
 # codec);
@@ -123,6 +125,7 @@ def main(argv=None) -> None:
                          "(per-suite wall breakdowns land in the artifact "
                          "regardless)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     algorithms = None
     if args.algorithms:
@@ -146,6 +149,7 @@ def main(argv=None) -> None:
                       "codec": args.codec,
                       "suites": {}}
     names = [args.only] if args.only else list(SUITES)
+    failed: list[str] = []
     t_total = time.perf_counter()
     for name in names:
         print(f"# ==== {name} ====")
@@ -163,7 +167,11 @@ def main(argv=None) -> None:
                 "rows": [list(r) for r in rows],
             }
         except Exception as e:  # noqa: BLE001
+            # Record the failure and keep going, so the other suites still
+            # land in the artifact; the run exits non-zero at the end.
+            traceback.print_exc()
             print(f"# {name}: FAILED {repr(e)[:300]}")
+            failed.append(name)
             artifact["suites"][name] = {
                 "wall_s": round(time.perf_counter() - t0, 2),
                 "error": repr(e)[:300],
@@ -196,6 +204,8 @@ def main(argv=None) -> None:
         with open(args.json, "w") as f:
             json.dump(artifact, f, indent=1)
         print(f"# wrote {os.path.normpath(args.json)}")
+    if failed:
+        sys.exit(f"benchmark suite(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -18,6 +18,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, lm_arch_ids
 from repro.core import ALGORITHMS, lm_workload
 from repro.core.timing import HardwareModel
@@ -39,6 +40,7 @@ def main():
                     help="client-update execution: vmapped host loop or "
                          "cluster-as-collective mesh dispatch")
     args = ap.parse_args()
+    use_compile_cache()
 
     wl = lm_workload(get_config(args.arch).reduced(), seq_len=args.seq,
                      samples_per_client=4 * args.batch)

@@ -11,6 +11,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import use_compile_cache
 from repro.core import ALGORITHMS
 from repro.orbits import WalkerStar, compute_access_windows, station_subnetwork
 from repro.sim import ConstellationSim, SimConfig
@@ -20,6 +21,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=60)
     args = ap.parse_args()
+    use_compile_cache()
 
     c = WalkerStar(clusters=5, sats_per_cluster=10)
     print(f"constellation: {c.n_sats} satellites "

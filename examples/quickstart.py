@@ -11,6 +11,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import use_compile_cache
 from repro.core import FedAvgSat, spaceify
 from repro.data import synth_femnist
 from repro.orbits import WalkerStar, station_subnetwork
@@ -18,6 +19,7 @@ from repro.sim import ConstellationSim, SimConfig
 
 
 def main():
+    use_compile_cache()
     constellation = WalkerStar(clusters=2, sats_per_cluster=5)
     stations = station_subnetwork(3)
     algorithm = spaceify(FedAvgSat(), schedule=True)   # + FLSchedule

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, lm_arch_ids
 from repro.models.lm import init_params
 from repro.models.lm.transformer import prefill
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     print(f"serving {cfg.name}: {cfg.n_layers}L d={cfg.d_model} "

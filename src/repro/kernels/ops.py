@@ -1,37 +1,25 @@
-"""Jit'd dispatch wrappers for the Pallas kernels.
+"""Pytree adapter for the Pallas fedagg kernel.
 
-On a real TPU backend the Mosaic kernels run natively; on CPU they run in
-interpret mode (exact same kernel body, executed in Python) — this is how
-the offline container validates them. `use_kernels()` can force either
-path; pure-jnp fallbacks live in ref.py.
+The kernels compile natively for the TPU (Mosaic). Interpret mode (the
+same kernel body, executed op by op on any backend) is never chosen for
+the caller: pass ``interpret=True`` explicitly, as the CPU tests do. Off a
+TPU, a call without it raises. Pure-jnp oracles live in ref.py.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
 from repro.kernels.fedagg import fedagg
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.prox_sgd import prox_sgd
-from repro.kernels.wkv6 import wkv6
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def fedagg_op(x: jax.Array, w: jax.Array) -> jax.Array:
-    return fedagg(x, w, interpret=_interpret())
-
-
-def fedagg_pytree(stacked, w: jax.Array):
+def fedagg_pytree(stacked, w: jax.Array, *, interpret: bool = False):
     """Weighted-average a stacked client pytree through the fedagg kernel."""
     leaves, treedef = jax.tree.flatten(stacked)
     K = leaves[0].shape[0]
     flat = jnp.concatenate(
         [l.reshape(K, -1).astype(jnp.float32) for l in leaves], axis=1)
-    out = fedagg_op(flat, w.astype(jnp.float32))
+    out = fedagg(flat, w.astype(jnp.float32), interpret=interpret)
     segs = []
     off = 0
     for l in leaves:
@@ -41,35 +29,4 @@ def fedagg_pytree(stacked, w: jax.Array):
     return treedef.unflatten(segs)
 
 
-def prox_sgd_op(w, g, w0, lr: float, mu: float):
-    return prox_sgd(w, g, w0, lr, mu, interpret=_interpret())
-
-
-def prox_sgd_pytree(params, grads, anchor, lr: float, mu: float):
-    flat = lambda t: jax.tree.leaves(t)
-    treedef = jax.tree.structure(params)
-    outs = [prox_sgd_op(p.reshape(-1), g.reshape(-1), a.reshape(-1), lr, mu
-                        ).reshape(p.shape)
-            for p, g, a in zip(flat(params), flat(grads), flat(anchor))]
-    return jax.tree.unflatten(treedef, outs)
-
-
-def flash_attention_op(q, k, v, *, causal=True, window=None, softcap=None,
-                       bq=None, bk=None):
-    kw = {}
-    if bq:
-        kw["bq"] = bq
-    if bk:
-        kw["bk"] = bk
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           softcap=softcap, interpret=_interpret(), **kw)
-
-
-def wkv6_op(r, k, v, logw, s0, *, chunk: int = 64):
-    return wkv6(r, k, v, logw, s0, chunk=chunk, interpret=_interpret())
-
-
-__all__ = [
-    "fedagg_op", "fedagg_pytree", "prox_sgd_op", "prox_sgd_pytree",
-    "flash_attention_op", "wkv6_op", "ref",
-]
+__all__ = ["fedagg_pytree"]

@@ -3,9 +3,11 @@
 The MXU-friendly chunk formulation of `models/lm/scan_core.py`: grid
 (B, H, nChunks) with the chunk dimension innermost ("arbitrary"); the
 (K, V) state lives in VMEM scratch and carries across chunk steps. Per
-chunk the kernel does three dense matmuls (inter, intra-scores, intra-out)
-plus exp/cumsum VPU work — decay products are exp() of differences of
-cumulative logs, all <= 0, so the kernel is overflow-free for any chunk.
+chunk the kernel does three dense matmuls (inter, intra-out, state carry),
+a triangular-ones matmul for the cumulative log decay, and the decayed
+intra-chunk scores as an (L, L, K) VPU product reduced over K — decay
+products are exp() of differences of cumulative logs, all <= 0, so the
+kernel is overflow-free for any chunk.
 
 Strict-past convention (o_t excludes i == t); callers add their diagonal
 term (RWKV's u-bonus / SSD's (C.B) x_t) outside — same contract as the
@@ -19,10 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 DEFAULT_CHUNK = 64
 
@@ -40,7 +38,15 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, s0_ref, o_ref, sT_ref, s_ref,
     v = v_ref[0, 0].astype(jnp.float32)              # (L, V)
     lw = w_ref[0, 0].astype(jnp.float32)             # (L, K) log decay <= 0
 
-    logc = jnp.cumsum(lw, axis=0)                    # inclusive
+    # Inclusive cumulative log decay as a product with a lower-triangular
+    # ones matrix: Mosaic has no cumsum lowering, and HIGHEST keeps the
+    # MXU from rounding the log decays to bf16.
+    incl = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    logc = jax.lax.dot_general(
+        incl.astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)          # (L, K)
     logb = logc - lw                                 # exclusive
     s = s_ref[...]                                   # (K, V)
 
@@ -51,8 +57,8 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, s0_ref, o_ref, sT_ref, s_ref,
 
     # Intra-chunk strict-lower-triangular attention.
     d = logb[:, None, :] - logc[None, :, :]          # (L, L, K)
-    a = jnp.einsum("tk,ik,tik->ti", r, k, jnp.exp(jnp.minimum(d, 0.0)),
-                   preferred_element_type=jnp.float32)
+    a = jnp.sum(r[:, None, :] * k[None, :, :] * jnp.exp(jnp.minimum(d, 0.0)),
+                axis=-1)                             # (L, L)
     tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
            > jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
     a = jnp.where(tri, a, 0.0)
@@ -106,7 +112,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
             jax.ShapeDtypeStruct((B, H, K, V), r.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, s0)

@@ -37,7 +37,6 @@ from repro.core.aggregation import (
     staleness_discount,
 )
 from repro.core.client import vmapped_client_update
-from repro.sharding.compat import shard_map
 
 
 def _pod_axis(mesh) -> str:
@@ -99,7 +98,9 @@ def make_fl_round_step(cfg=None, mesh=None, lr: float = 1e-3,
         # Inside shard_map over `axis`: batch is this pod's shard, weight
         # is this pod's scalar participation weight.
         w = weight[0] * staleness_discount(staleness[0])
-        local = params
+        # The replicated params seed a carry that comes back varying over
+        # the pod axis (each pod trains on its own shard).
+        local = jax.lax.pcast(params, (axis,), to="varying")
 
         def body(i, local):
             g = grad_fn(local, batch)
@@ -128,7 +129,7 @@ def make_fl_round_step(cfg=None, mesh=None, lr: float = 1e-3,
         if staleness is None:
             staleness = jnp.zeros((n_pods,), jnp.int32)
         specs = {k: batch_specs[k] for k in batch}
-        return shard_map(
+        return jax.shard_map(
             pod_round,
             mesh=mesh,
             in_specs=(P(), specs, P(axis), P(axis), P(axis)),
@@ -183,7 +184,7 @@ def make_mesh_round_step(loss_fn, mesh, *, lr: float, batch_size: int,
 
     def round_step(global_params, anchors, x, y, n, steps, weights,
                    staleness, prox_mu, rngs):
-        return shard_map(
+        return jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(P(), P(axis), P(axis), P(axis), P(axis), P(axis),

@@ -73,7 +73,7 @@ def _dispatch_tokens(xf, gate_vals, expert_ids, E: int, C: int):
     T, d = xf.shape
     K = expert_ids.shape[-1]
     flat_e = expert_ids.reshape(-1)                           # (T*K,)
-    flat_t = jnp.repeat(jnp.arange(T), K)
+    flat_t = jnp.arange(T * K) // K
     flat_g = gate_vals.reshape(-1)
     order = jnp.argsort(flat_e)
     se, st, sg = flat_e[order], flat_t[order], flat_g[order]
@@ -192,12 +192,11 @@ def apply_moe_ep(p: dict, x: jax.Array, cfg: MoEConfig, mlp_kind: str,
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.compat import shard_map
     gated = mlp_kind in ("swiglu", "geglu")
     in_specs = (P(dp_axes), P(), P(axis), P(axis),
                 P(axis) if gated else P(), P())
     out_specs = (P(dp_axes), {"load_balance": P(), "router_z": P()})
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         axis_names=set(dp_axes) | {axis},
     )(x, p["router"], p["w1"], p["w2"],

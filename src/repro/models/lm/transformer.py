@@ -433,7 +433,9 @@ def _logits(cfg: ModelConfig, params, x):
 def _scan_segments(cfg: ModelConfig, params, x, positions, mode: str,
                    caches=None, pos=None, max_seq=None, enc_out=None):
     """Run every segment with lax.scan over its stacked layers."""
-    aux_total = jnp.zeros((), jnp.float32)
+    # zeros_like inherits x's varying mesh axes, so the scan carry keeps
+    # one type when this runs inside a shard_map body.
+    aux_total = jnp.zeros_like(x, shape=(), dtype=jnp.float32)
     new_caches = []
     for i, seg in enumerate(cfg.resolved_segments):
         sp = params["segments"][i]

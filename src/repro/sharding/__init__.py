@@ -1,4 +1,3 @@
-from repro.sharding.compat import abstract_mesh, shard_map
 from repro.sharding.flmesh import client_mesh, pad_client_count
 from repro.sharding.specs import (
     param_pspecs,
@@ -8,4 +7,4 @@ from repro.sharding.specs import (
 )
 
 __all__ = ["param_pspecs", "batch_pspec", "cache_pspecs", "MeshAxes",
-           "abstract_mesh", "shard_map", "client_mesh", "pad_client_count"]
+           "client_mesh", "pad_client_count"]

@@ -74,6 +74,7 @@ from repro.sim.engine import (
     buffer_weights,
     client_steps,
     sync_round_metrics,
+    traced_jit_call,
 )
 from repro.sim.metrics import SimResult
 
@@ -529,10 +530,7 @@ class BatchedSweep:
                 if C > n:
                     rngs[b, n:] = rr[0]
             bound = ConstellationSim._bound(np.maximum(steps, 1))
-            fresh = (bound, C) not in self._updaters
             update = self._updater(bound, C)
-            if fresh:
-                count("sim.jit_compiles")
 
             with span("sim.round", idx=r, mode="batched",
                       scenarios=len(active)):
@@ -549,12 +547,11 @@ class BatchedSweep:
                     bidx = jnp.arange(B)[:, None]
                     anchors = jax.tree.map(lambda hv: hv[vrel, bidx], vstk)
                 with span("sim.client_train", mode="batched",
-                          scenarios=len(active), step_bound=bound,
-                          jit_compile=fresh):
-                    out = update(anchors, anchors, jnp.asarray(x),
-                                 jnp.asarray(y), jnp.asarray(nv),
-                                 jnp.asarray(steps), prox,
-                                 jnp.asarray(rngs))
+                          scenarios=len(active), step_bound=bound) as sp:
+                    out = traced_jit_call(
+                        sp, update, anchors, anchors, jnp.asarray(x),
+                        jnp.asarray(y), jnp.asarray(nv), jnp.asarray(steps),
+                        prox, jnp.asarray(rngs))
                     if self.codec.lossy:
                         # Same per-client codec round-trip as the loop
                         # engine (same rng keys: split(sub, n) rows), so

@@ -100,6 +100,25 @@ def client_steps(n_k: int, epochs: int, batch_size: int,
     return int(np.clip(epochs * spe, 1, max_steps))
 
 
+def traced_jit_call(sp, jitted, *args):
+    """Call the `jax.jit` function `jitted` inside the open span `sp`.
+
+    Whether the call compiled is read from the function's own executable
+    cache, so a retrace for new shapes or shardings is caught as well as
+    the first call: `sp` gets `jit_compile`, and `sim.jit_compiles` counts
+    each one.
+    """
+    before = jitted._cache_size()
+    out = jitted(*args)
+    if obs_enabled():
+        jax.block_until_ready(out)   # honest walls; values untouched
+    compiled = jitted._cache_size() > before
+    sp.set(jit_compile=compiled)
+    if compiled:
+        count("sim.jit_compiles")
+    return out
+
+
 def sync_round_metrics(plans, t_start: float, t_end: float) -> dict:
     """Per-satellite round metrics from a synchronous round's ClientPlans —
     the kwargs `_finish_round` consumes. Shared by `_run_sync` and the
@@ -361,20 +380,11 @@ class ConstellationSim:
                 global_params)
         rngs = jax.random.split(rng, len(ks))
         bound = self._bound(steps_np)
-        # jit-compile detection: a (bound, anchored) key this run has not
-        # dispatched yet pays XLA compilation inside its first call, so
-        # the span's first-call timing isolates compile from steady-state.
-        fresh = (bound, anchored) not in self._updaters
         update = self._updater(bound, anchored=anchored)
-        if fresh:
-            count("sim.jit_compiles")
-        with span("sim.client_train", clients=len(ks), step_bound=bound,
-                  jit_compile=fresh):
-            out = update(params0, anchors, x, y, n, steps,
-                         self.alg.strategy.prox_mu, rngs)
-            if obs_enabled():
-                jax.block_until_ready(out)   # honest walls; values untouched
-        return out
+        with span("sim.client_train", clients=len(ks),
+                  step_bound=bound) as sp:
+            return traced_jit_call(sp, update, params0, anchors, x, y, n,
+                                   steps, self.alg.strategy.prox_mu, rngs)
 
     def _run_clients_mesh(self, global_params, ks: list[int],
                           epochs: list[int], rng, *, weights, staleness,
@@ -417,18 +427,12 @@ class ConstellationSim:
                     [s, jnp.broadcast_to(g, (pad,) + g.shape)]),
                 anchors, global_params)
         bound = self._bound(steps_np)
-        fresh = (bound, int(mesh.shape[self.workload.mesh_axis])) \
-            not in self._mesh_steps
         step_fn = self._mesh_step(bound, mesh)
-        if fresh:
-            count("sim.jit_compiles")
         with span("sim.client_train", mode="mesh", clients=len(ks),
-                  step_bound=bound, jit_compile=fresh):
-            out = step_fn(global_params, anchors, x, y, n, steps, w, stale,
-                          self.alg.strategy.prox_mu, rngs)
-            if obs_enabled():
-                jax.block_until_ready(out)
-        return out
+                  step_bound=bound) as sp:
+            return traced_jit_call(sp, step_fn, global_params, anchors, x, y,
+                                   n, steps, w, stale,
+                                   self.alg.strategy.prox_mu, rngs)
 
     def _codec_roundtrip(self, anchored: bool):
         """Jitted vmapped encode/decode of the stacked client returns.

@@ -184,8 +184,8 @@ def test_mesh_round_step_matches_vmapped_client_update():
 def test_fl_round_lowers_on_production_mesh():
     """The FL round step lowers against the 2x16x16 multi-pod mesh specs
     (AbstractMesh: no devices needed)."""
-    from repro.sharding import abstract_mesh
-    mesh = abstract_mesh((2, 2, 2), ("pod", "data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("gemma-2b").reduced()
     params_s = jax.eval_shape(lambda k: init_params(cfg, k),
                               jax.random.PRNGKey(0))

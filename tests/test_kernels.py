@@ -125,9 +125,29 @@ def test_fedagg_pytree_roundtrip():
             "b": {"c": _rand(rng, (4, 7), jnp.float32)}}
     w = jnp.asarray([1.0, 2.0, 3.0, 4.0])
     wn = w / w.sum()
-    out = fedagg_pytree(tree, wn)
+    out = fedagg_pytree(tree, wn, interpret=True)
     ref = weighted_average(tree, w)
     for k_, o, r_ in (("a", out["a"], ref["a"]),
                       ("c", out["b"]["c"], ref["b"]["c"])):
         np.testing.assert_allclose(np.asarray(o), np.asarray(r_),
                                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kernel", ["fedagg", "prox_sgd", "flash_attention",
+                                    "wkv6"])
+def test_kernel_without_interpret_raises_off_tpu(kernel):
+    """Interpret mode is opt-in: off a TPU, a native call must fail loudly
+    instead of quietly running the kernel body in Python."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("native kernels run on a TPU")
+    x = jnp.ones((2, 1, 64, 32), jnp.float32)
+    calls = {
+        "fedagg": lambda: fedagg(jnp.ones((2, 100)), jnp.ones((2,))),
+        "prox_sgd": lambda: prox_sgd(jnp.ones(130), jnp.ones(130),
+                                     jnp.ones(130), 0.05, 0.1),
+        "flash_attention": lambda: flash_attention(x, x, x, bq=32, bk=32),
+        "wkv6": lambda: wkv6(x, x, x, -jnp.abs(x), jnp.zeros((2, 1, 32, 32)),
+                             chunk=32),
+    }
+    with pytest.raises(ValueError, match="interpret"):
+        calls[kernel]()

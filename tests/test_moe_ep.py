@@ -45,13 +45,13 @@ def test_ep_differentiable(setup):
 def test_ep_lowers_on_abstract_production_mesh():
     """EP compiles symbolically against a (data=4, model=2) mesh where the
     all_to_all is non-trivial (E=4 experts over 4 shards)."""
-    from repro.sharding import abstract_mesh
+    from jax.sharding import AbstractMesh
     cfg = MoEConfig(n_experts=4, top_k=2, d_ff_expert=64,
                     capacity_factor=4.0)
     p = jax.eval_shape(lambda k: init_moe(k, 32, cfg, "swiglu"),
                        jax.random.PRNGKey(0))
     x = jax.ShapeDtypeStruct((8, 128, 32), jnp.float32)
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     out = jax.eval_shape(
         lambda pp, xx: apply_moe_ep(pp, xx, cfg, "swiglu", ("data",),
                                     "data", 4, mesh), p, x)

@@ -6,6 +6,8 @@ import math
 import threading
 import time
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from repro import obs
@@ -146,10 +148,14 @@ def test_max_events_cap_drops_and_reports():
 
 
 def test_threaded_spans_keep_independent_stacks():
+    # All four threads hold their spans at once, so their idents (which
+    # the OS may reuse after a thread exits) are distinct.
+    barrier = threading.Barrier(4, timeout=30)
+
     def worker():
         with obs.span("outer"):
             with obs.span("inner"):
-                time.sleep(0.001)
+                barrier.wait()
 
     with obs.tracing() as t:
         threads = [threading.Thread(target=worker) for _ in range(4)]
@@ -306,3 +312,47 @@ def test_traced_run_bitwise_identical_and_instrumented():
     # round spans enclose their select/eval children
     rounds = [ev for ev in t.events if ev["name"] == "sim.round"]
     assert all(ev["depth"] == 0 for ev in rounds)
+
+
+def test_traced_jit_call_flags_every_compile():
+    """A retrace for a new input shape is a compile too, not only the
+    first call of a jitted function."""
+    from repro.sim.engine import traced_jit_call
+
+    f = jax.jit(lambda x: x * 2)
+    with obs.tracing() as t:
+        for n in (2, 2, 3, 3):
+            with obs.span("sim.client_train") as sp:
+                traced_jit_call(sp, f, jnp.ones(n))
+        s = obs.metrics_summary()
+    assert [ev["args"]["jit_compile"] for ev in t.events] == \
+        [True, False, True, False]
+    assert s["counters"]["sim.jit_compiles"] == 2
+
+
+def test_mesh_fedbuff_flags_its_retraces():
+    """The mesh step's output params carry the client mesh's sharding, so
+    the second and third FedBuff flushes retrace; every compile of the
+    step is flagged on its `sim.client_train` span."""
+    from repro.core import ALGORITHMS
+    from repro.data import synth_femnist
+    from repro.orbits import (
+        WalkerStar,
+        compute_access_windows,
+        station_subnetwork,
+    )
+    from repro.sim import ConstellationSim, SimConfig
+
+    c, st = WalkerStar(2, 3), station_subnetwork(3)
+    aw = compute_access_windows(c, st, horizon_s=4 * 86400.0)
+    cfg = SimConfig(max_rounds=4, horizon_s=4 * 86400.0, eval_every=4,
+                    max_steps=4, seed=0)
+    sim = ConstellationSim(c, st, ALGORITHMS["fedbuff"], cfg=cfg, access=aw,
+                           data=synth_femnist(c.n_sats, seed=0),
+                           workload="femnist_mlp", execution="mesh")
+    with obs.tracing() as t:
+        sim.run()
+    flags = [ev["args"]["jit_compile"] for ev in t.events
+             if ev["name"] == "sim.client_train"]
+    compiles = sum(f._cache_size() for f in sim._mesh_steps.values())
+    assert sum(flags) == compiles > len(sim._mesh_steps)

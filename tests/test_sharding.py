@@ -13,8 +13,8 @@ from repro.sharding.specs import batch_pspec, param_pspecs
 def mesh44():
     # 16 logical devices are not available under pytest (1 CPU device), so
     # rules are exercised against an abstract mesh via AbstractMesh.
-    from repro.sharding import abstract_mesh
-    return abstract_mesh((4, 4), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    return AbstractMesh((4, 4), ("data", "model"))
 
 
 def test_param_specs_cover_tree(mesh44):
