@@ -1,0 +1,10 @@
+"""The benchmark's tests run on the CPU, from the repository root, with
+the program's `src` and the root (for the `bench` package) on the path."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for path in (os.path.join(ROOT, "src"), ROOT):
+    if path not in sys.path:
+        sys.path.insert(0, path)
