@@ -15,15 +15,25 @@ Design constraints, in order:
    same numeric code — tracing never touches values, only observes walls.
 2. **Thread-safe.** Spans nest per-thread (a `threading.local` stack);
    finished events and counters are appended/merged under one lock.
-3. **Two clocks.** Every span records `time.perf_counter()` (monotonic,
-   for durations — immune to NTP steps) *and* `time.time()` (wall, for
-   correlating with external logs).
+3. **One clock per span.** A live span reads `time.perf_counter()` only
+   (monotonic, immune to NTP steps); its wall time is the tracer's
+   `t0_wall` plus its offset, for correlating with external logs.
+4. **On the profiler's clock.** While a tracer is installed each span
+   also opens a `jax.profiler.TraceAnnotation` of the same name on its
+   own thread, so a profiler session (`jax.profiler.start_trace`) stamps
+   the program's spans on its host plane, beside the device ops. With
+   no profiler session running an annotation records nothing. jax is
+   imported when a tracer is made, never by importing this module.
+5. **Observing is not blocking.** `Tracer.sync` (default True) asks the
+   instrumented code to wait for the device inside its spans, so span
+   walls include device time; with `sync=False` tracing never waits on
+   the device and never reads a device value (`syncing()`).
 
 Usage::
 
     from repro.obs import span, count, enable, metrics_summary
 
-    enable()
+    enable()                    # or enable(sync=False): never block
     with span("sim.round", idx=3):
         with span("sim.select"):
             ...
@@ -36,6 +46,7 @@ Exporters (Chrome/Perfetto trace.json, flat JSONL) live in
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
 import threading
 import time
@@ -62,7 +73,8 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One live span. Created only while tracing is enabled."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_wall0", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_id",
+                 "_parent", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -74,15 +86,20 @@ class _Span:
         self.args.update(args)
 
     def __enter__(self):
-        stack = self._tracer._stack()
+        tracer = self._tracer
+        stack = tracer._stack()
         self._depth = len(stack)
+        self._parent = stack[-1]._id if stack else None
+        self._id = next(tracer._ids)
         stack.append(self)
-        self._wall0 = time.time()
+        self._note = tracer._annotation(self.name)
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._note.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -93,11 +110,18 @@ class _Span:
 
 
 class Tracer:
-    """Event + counter registry for one tracing session."""
+    """Event + counter registry for one tracing session.
 
-    def __init__(self, max_events: int = 1_000_000):
+    `sync` is what `syncing()` reports while this tracer is installed:
+    whether instrumented code may wait on the device to time it."""
+
+    def __init__(self, max_events: int = 1_000_000, sync: bool = True):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self._lock = threading.Lock()
         self._tls = threading.local()
+        self._ids = itertools.count()  # open order; next() is atomic
+        self.sync = bool(sync)
         self.max_events = int(max_events)
         self.events: list[dict] = []   # finished spans, completion order
         self.counters: dict[str, float] = {}
@@ -120,13 +144,16 @@ class Tracer:
         return _Span(self, name, args)
 
     def _record(self, sp: _Span, t1: float) -> None:
+        ts = sp._t0 - self.t0_mono
         ev = {
             "name": sp.name,
-            "ts_us": (sp._t0 - self.t0_mono) * 1e6,
+            "ts_us": ts * 1e6,
             "dur_us": (t1 - sp._t0) * 1e6,
-            "t_wall": sp._wall0,
+            "t_wall": self.t0_wall + ts,
             "tid": threading.get_ident(),
             "depth": sp._depth,
+            "id": sp._id,            # this span, numbered in open order
+            "parent": sp._parent,    # the enclosing span's id, or None
             "args": sp.args,
         }
         with self._lock:
@@ -139,6 +166,11 @@ class Tracer:
     def count(self, name: str, n: float = 1) -> None:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + n
+
+    def counter(self, name: str) -> float:
+        """A counter's current value (0 before its first bump)."""
+        with self._lock:
+            return self.counters.get(name, 0)
 
     # --------------------------------------------------------- summary --
     def summary(self) -> dict:
@@ -185,10 +217,10 @@ class Tracer:
 _tracer: Tracer | None = None
 
 
-def enable(max_events: int = 1_000_000) -> Tracer:
+def enable(max_events: int = 1_000_000, sync: bool = True) -> Tracer:
     """Install (and return) a fresh global tracer."""
     global _tracer
-    _tracer = Tracer(max_events=max_events)
+    _tracer = Tracer(max_events=max_events, sync=sync)
     return _tracer
 
 
@@ -199,6 +231,14 @@ def disable() -> None:
 
 def enabled() -> bool:
     return _tracer is not None
+
+
+def syncing() -> bool:
+    """May instrumented code wait on the device (or read a device value)
+    to time its span? True only while a tracer with `sync=True` is
+    installed."""
+    t = _tracer
+    return t is not None and t.sync
 
 
 def get_tracer() -> Tracer | None:
@@ -227,12 +267,12 @@ def metrics_summary() -> dict:
 
 
 @contextlib.contextmanager
-def tracing(max_events: int = 1_000_000):
+def tracing(max_events: int = 1_000_000, sync: bool = True):
     """Scoped tracing session (tests): enable, yield the tracer, restore
     whatever tracer — usually None — was installed before."""
     global _tracer
     prev = _tracer
-    t = Tracer(max_events=max_events)
+    t = Tracer(max_events=max_events, sync=sync)
     _tracer = t
     try:
         yield t

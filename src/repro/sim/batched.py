@@ -68,12 +68,15 @@ from repro.core.selection import (
     ScheduleSelector,
 )
 from repro.core.strategies.base import ClientWorkMode, Strategy
-from repro.obs import count, enabled as obs_enabled, span
+from repro.obs import count, span, syncing
 from repro.sim.engine import (
     ConstellationSim,
     buffer_weights,
     client_steps,
+    run_span,
     sync_round_metrics,
+    to_device,
+    to_host,
     traced_jit_call,
 )
 from repro.sim.metrics import SimResult
@@ -418,10 +421,11 @@ class BatchedSweep:
         return self._codec_rt
 
     def run(self) -> list[SimResult]:
-        planned, twins = self.plan()
-        if not self.train:
-            return planned
-        return self._train_batch(planned, twins)
+        with run_span(execution="batched", scenarios=len(self.sims)):
+            planned, twins = self.plan()
+            if not self.train:
+                return planned
+            return self._train_batch(planned, twins)
 
     def _train_batch(self, planned: list[SimResult],
                      twins: list[ConstellationSim]) -> list[SimResult]:
@@ -457,7 +461,7 @@ class BatchedSweep:
             for b, i in enumerate(fed):
                 results[i] = dataclasses.replace(
                     planned[i], execution="batched",
-                    final_params=jax.device_get(
+                    final_params=to_host(
                         jax.tree.map(lambda l, b=b: l[b], G)))
             return results
 
@@ -483,52 +487,55 @@ class BatchedSweep:
         agg = self._aggregate()
         # Sync strategies aggregate with weighted_average, which has no
         # server-lr knob — pin 1.0 so the delta form reduces to it exactly.
-        slr = jnp.asarray(
+        slr = to_device(
             [1.0 if sims[i].alg.synchronous
              else getattr(sims[i].alg.strategy, "server_lr", 1.0)
              for i in fed], jnp.float32)
-        prox = jnp.asarray([sims[i].alg.strategy.prox_mu for i in fed],
-                           jnp.float32)
+        prox = to_device([sims[i].alg.strategy.prox_mu for i in fed],
+                         jnp.float32)
 
         for r in range(R):
             active = [b for b in range(B) if r < n_rounds[b]]
-            steps = np.zeros((B, C), np.int32)
-            w = np.zeros((B, C), np.float32)
-            stale = np.zeros((B, C), np.int32)
-            nv = np.zeros((B, C), np.int32)
-            vs = np.full((B, C), r, np.int64)
-            x = np.zeros((B, C, N) + x0.shape[2:], x0.dtype)
-            y = np.zeros((B, C, N), y0.dtype)
-            rngs = np.zeros((B, C, 2), np.uint32)
-            for b in active:
-                sim = sims[fed[b]]
-                rec = results[fed[b]].rounds[r]
-                ks = rec.participants
-                n = len(ks)
-                ks_p = list(ks) + [ks[0]] * (C - n)
-                data = sim.data
-                st = np.asarray(rec.staleness, np.int64)
-                steps[b, :n] = [client_steps(int(data.n[k]), e,
-                                             sim.cfg.batch_size,
-                                             sim.cfg.max_steps)
-                                for k, e in zip(ks, rec.epochs)]
-                ns = np.asarray([float(data.n[k]) for k in ks], np.float32)
-                if sim.alg.synchronous:
-                    w[b, :n] = ns
-                else:
-                    w[b, :n] = buffer_weights(
-                        ns, st.astype(np.int32),
-                        sim.alg.strategy.max_staleness)
-                    stale[b, :n] = st
-                    vs[b, :n] = r - st
-                nb = data.x.shape[1]
-                x[b, :, :nb] = data.x[ks_p]
-                y[b, :, :nb] = data.y[ks_p]
-                nv[b] = data.n[ks_p]
-                rr = np.asarray(jax.random.split(subs[b][r], n))
-                rngs[b, :n] = rr
-                if C > n:
-                    rngs[b, n:] = rr[0]
+            with span("sim.batched.assemble", round=r,
+                      scenarios=len(active)):
+                steps = np.zeros((B, C), np.int32)
+                w = np.zeros((B, C), np.float32)
+                stale = np.zeros((B, C), np.int32)
+                nv = np.zeros((B, C), np.int32)
+                vs = np.full((B, C), r, np.int64)
+                x = np.zeros((B, C, N) + x0.shape[2:], x0.dtype)
+                y = np.zeros((B, C, N), y0.dtype)
+                rngs = np.zeros((B, C, 2), np.uint32)
+                for b in active:
+                    sim = sims[fed[b]]
+                    rec = results[fed[b]].rounds[r]
+                    ks = rec.participants
+                    n = len(ks)
+                    ks_p = list(ks) + [ks[0]] * (C - n)
+                    data = sim.data
+                    st = np.asarray(rec.staleness, np.int64)
+                    steps[b, :n] = [client_steps(int(data.n[k]), e,
+                                                 sim.cfg.batch_size,
+                                                 sim.cfg.max_steps)
+                                    for k, e in zip(ks, rec.epochs)]
+                    ns = np.asarray([float(data.n[k]) for k in ks],
+                                    np.float32)
+                    if sim.alg.synchronous:
+                        w[b, :n] = ns
+                    else:
+                        w[b, :n] = buffer_weights(
+                            ns, st.astype(np.int32),
+                            sim.alg.strategy.max_staleness)
+                        stale[b, :n] = st
+                        vs[b, :n] = r - st
+                    nb = data.x.shape[1]
+                    x[b, :, :nb] = data.x[ks_p]
+                    y[b, :, :nb] = data.y[ks_p]
+                    nv[b] = data.n[ks_p]
+                    rr = to_host(jax.random.split(subs[b][r], n))
+                    rngs[b, :n] = rr
+                    if C > n:
+                        rngs[b, n:] = rr[0]
             bound = ConstellationSim._bound(np.maximum(steps, 1))
             update = self._updater(bound, C)
 
@@ -543,27 +550,27 @@ class BatchedSweep:
                     vstk = jax.tree.map(
                         lambda *xs: jnp.stack(xs),
                         *[hist[v] for v in range(v_lo, r + 1)])
-                    vrel = jnp.asarray(vs - v_lo)
+                    vrel = to_device(vs - v_lo)
                     bidx = jnp.arange(B)[:, None]
                     anchors = jax.tree.map(lambda hv: hv[vrel, bidx], vstk)
                 with span("sim.client_train", mode="batched",
                           scenarios=len(active), step_bound=bound) as sp:
+                    keys = to_device(rngs)
                     out = traced_jit_call(
-                        sp, update, anchors, anchors, jnp.asarray(x),
-                        jnp.asarray(y), jnp.asarray(nv), jnp.asarray(steps),
-                        prox, jnp.asarray(rngs))
+                        sp, update, anchors, anchors, to_device(x),
+                        to_device(y), to_device(nv), to_device(steps),
+                        prox, keys)
                     if self.codec.lossy:
                         # Same per-client codec round-trip as the loop
                         # engine (same rng keys: split(sub, n) rows), so
                         # the decoded returns match client for client.
-                        out = self._codec_roundtrip()(
-                            out, anchors, jnp.asarray(rngs))
-                    if obs_enabled():
+                        out = self._codec_roundtrip()(out, anchors, keys)
+                    if syncing():
                         jax.block_until_ready(out)
                 with span("sim.aggregate", mode="batched",
                           scenarios=len(active)):
-                    G = agg(G, out, jnp.asarray(w), jnp.asarray(stale), slr)
-                    if obs_enabled():
+                    G = agg(G, out, to_device(w), to_device(stale), slr)
+                    if syncing():
                         jax.block_until_ready(G)
                 hist[r + 1] = G
                 if r + 1 < R:
@@ -596,7 +603,7 @@ class BatchedSweep:
         for b, i in enumerate(fed):
             results[i] = dataclasses.replace(
                 results[i], accuracy_curve=curves[b], execution="batched",
-                final_params=jax.device_get(
+                final_params=to_host(
                     jax.tree.map(lambda l, b=b: l[b], G)))
         return results
 

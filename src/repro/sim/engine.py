@@ -45,6 +45,7 @@ overridable per run with `ConstellationSim(..., execution=...)`):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 from typing import Iterable
@@ -68,7 +69,13 @@ from repro.core.timing import HardwareModel
 from repro.core.workload import Workload, get_workload, validate_execution
 from repro.data.federated import FederatedDataset
 from repro.models.femnist_mlp import femnist_mlp_apply, femnist_mlp_init
-from repro.obs import count, enabled as obs_enabled, span
+from repro.obs import (
+    count,
+    enabled as obs_enabled,
+    get_tracer,
+    span,
+    syncing,
+)
 from repro.orbits import constants as C
 from repro.orbits.access import AccessWindows, compute_access_windows
 from repro.orbits.walker import WalkerStar
@@ -100,17 +107,68 @@ def client_steps(n_k: int, epochs: int, batch_size: int,
     return int(np.clip(epochs * spe, 1, max_steps))
 
 
+TRAFFIC_COUNTERS = ("sim.h2d_bytes", "sim.d2h_bytes", "sim.host_syncs")
+
+
+def to_device(x, dtype=None) -> jax.Array:
+    """`jnp.asarray(x, dtype)`. Where `x` is on the host this uploads it,
+    and the bytes it takes on the device are counted in `sim.h2d_bytes`;
+    a device array passes through uncounted."""
+    out = jnp.asarray(x, dtype)
+    if obs_enabled() and not isinstance(x, jax.Array):
+        count("sim.h2d_bytes", out.nbytes)
+    return out
+
+
+def to_host(tree):
+    """Read device values to the host (`jax.device_get`): one host sync,
+    counted in `sim.host_syncs`, and its bytes in `sim.d2h_bytes`.
+
+    Every device-to-host read of a run goes through here and is explicit,
+    so an implicit one (`float(x)`, `np.asarray(x)`) stands out: on an
+    accelerator it fails under
+    `jax.transfer_guard_device_to_host("disallow")`."""
+    out = jax.device_get(tree)
+    if obs_enabled():
+        count("sim.host_syncs")
+        count("sim.d2h_bytes",
+              sum(np.asarray(a).nbytes for a in jax.tree.leaves(out)))
+    return out
+
+
+@contextlib.contextmanager
+def run_span(**args):
+    """The `sim.run` span around one whole run (`ConstellationSim.run`,
+    `BatchedSweep.run`). On a clean exit it carries the run's host-device
+    traffic as args `h2d_bytes`, `d2h_bytes` and `host_syncs`: how much
+    each of `TRAFFIC_COUNTERS` grew during the run (counters are global to
+    the tracer; the args price one run from its span alone)."""
+    tracer = get_tracer()
+    with span("sim.run", **args) as sp:
+        if tracer is None:
+            yield
+            return
+        before = [tracer.counter(c) for c in TRAFFIC_COUNTERS]
+        yield
+        sp.set(**{c.split(".", 1)[1]: tracer.counter(c) - b
+                  for c, b in zip(TRAFFIC_COUNTERS, before)})
+
+
 def traced_jit_call(sp, jitted, *args):
     """Call the `jax.jit` function `jitted` inside the open span `sp`.
 
-    Whether the call compiled is read from the function's own executable
-    cache, so a retrace for new shapes or shardings is caught as well as
-    the first call: `sp` gets `jit_compile`, and `sim.jit_compiles` counts
-    each one.
+    While a tracer is installed, whether the call compiled is read from
+    the function's own executable cache, so a retrace for new shapes or
+    shardings is caught as well as the first call: `sp` gets
+    `jit_compile`, and `sim.jit_compiles` counts each one. With a
+    syncing tracer the call is waited for, so the span's wall holds the
+    device time.
     """
+    if not obs_enabled():
+        return jitted(*args)
     before = jitted._cache_size()
     out = jitted(*args)
-    if obs_enabled():
+    if syncing():
         jax.block_until_ready(out)   # honest walls; values untouched
     compiled = jitted._cache_size() > before
     sp.set(jit_compile=compiled)
@@ -342,10 +400,11 @@ class ConstellationSim:
     # ------------------------------------------------------------------ #
     def run(self) -> SimResult:
         K = self.constellation.n_sats
-        if K < 2:
-            # A single satellite cannot federate (heatmap top-left = 0).
-            return self._result([], [], None)
-        return self._run_events()
+        with run_span(execution=self.execution, sats=K):
+            if K < 2:
+                # A single satellite cannot federate (heatmap top-left = 0).
+                return self._result([], [], None)
+            return self._run_events()
 
     # ------------------------------------------------------------------ #
     def _steps_for(self, k: int, epochs: int) -> int:
@@ -366,10 +425,10 @@ class ConstellationSim:
         parameter returns.
         """
         steps_np = [self._steps_for(k, e) for k, e in zip(ks, epochs)]
-        steps = jnp.asarray(steps_np, jnp.int32)
-        x = jnp.asarray(self.data.x[ks])
-        y = jnp.asarray(self.data.y[ks])
-        n = jnp.asarray(self.data.n[ks])
+        steps = to_device(steps_np, jnp.int32)
+        x = to_device(self.data.x[ks])
+        y = to_device(self.data.y[ks])
+        n = to_device(self.data.n[ks])
         anchored = anchors is not None
         if anchored:
             params0 = anchors
@@ -405,13 +464,13 @@ class ConstellationSim:
         total = pad_client_count(len(ks), mesh, self.workload.mesh_axis)
         pad = total - len(ks)
         ks_p = list(ks) + [ks[0]] * pad      # real rows; steps 0 mask them
-        x = jnp.asarray(self.data.x[ks_p])
-        y = jnp.asarray(self.data.y[ks_p])
-        n = jnp.asarray(self.data.n[ks_p])
-        steps = jnp.asarray(steps_np + [0] * pad, jnp.int32)
-        w = jnp.concatenate([jnp.asarray(weights, jnp.float32),
+        x = to_device(self.data.x[ks_p])
+        y = to_device(self.data.y[ks_p])
+        n = to_device(self.data.n[ks_p])
+        steps = to_device(steps_np + [0] * pad, jnp.int32)
+        w = jnp.concatenate([to_device(weights, jnp.float32),
                              jnp.zeros((pad,), jnp.float32)])
-        stale = jnp.concatenate([jnp.asarray(staleness, jnp.int32),
+        stale = jnp.concatenate([to_device(staleness, jnp.int32),
                                  jnp.zeros((pad,), jnp.int32)])
         rngs = jax.random.split(rng, len(ks))   # identical to the host path
         if pad:
@@ -467,18 +526,18 @@ class ConstellationSim:
             rt = self._codec_roundtrip(anchored)
             decoded = rt(stacked, anchors if anchored else global_params,
                          rngs)
-            if obs_enabled():
-                err = sum(float(jnp.sum((a - b) ** 2))
+            if syncing():
+                err = sum(jnp.sum((a - b) ** 2)
                           for a, b in zip(jax.tree.leaves(stacked),
                                           jax.tree.leaves(decoded)))
-                count("comms.codec_error", float(np.sqrt(err)))
+                count("comms.codec_error", float(np.sqrt(to_host(err))))
             stacked = decoded
         with span("sim.aggregate", strategy=self.alg.strategy.name,
                   clients=len(ks)):
             out = self.alg.strategy.aggregate(
-                global_params, stacked, jnp.asarray(weights),
-                jnp.asarray(staleness))
-            if obs_enabled():
+                global_params, stacked, to_device(weights),
+                to_device(staleness))
+            if syncing():
                 jax.block_until_ready(out)
         return out
 
@@ -511,7 +570,7 @@ class ConstellationSim:
             execution=self.execution,
         )
         if self.cfg.record_params and global_params is not None:
-            self._params_hist.append(jax.device_get(global_params))
+            self._params_hist.append(to_host(global_params))
         if do_eval:
             # The eval slot exists in the round protocol whether or not
             # this run trains; timing-only sweeps record it as an empty
@@ -548,7 +607,7 @@ class ConstellationSim:
 
     def _result(self, rounds: list[RoundRecord], curve: list,
                 global_params) -> SimResult:
-        final = (jax.device_get(global_params)
+        final = (to_host(global_params)
                  if (self.cfg.train and global_params is not None) else None)
         return SimResult(self.alg.name, self.constellation.n_sats,
                          len(self.stations), rounds, curve,
@@ -577,10 +636,10 @@ class ConstellationSim:
         if pad:
             n_eval[len(ks):] = 0  # masked out of the weighted accuracy
         acc = self.workload.eval_fn(global_params,
-                                    jnp.asarray(self.data.x_eval[ks_p]),
-                                    jnp.asarray(self.data.y_eval[ks_p]),
-                                    jnp.asarray(n_eval))
-        return float(acc)
+                                    to_device(self.data.x_eval[ks_p]),
+                                    to_device(self.data.y_eval[ks_p]),
+                                    to_device(n_eval))
+        return float(to_host(acc))
 
     # ------------------------------------------------------------------ #
     # Strategy-driven event loop
@@ -699,8 +758,8 @@ class ConstellationSim:
                         global_params = self._train_round(
                             global_params, ks, [p.epochs for p in sub],
                             sub_rng,
-                            weights=jnp.asarray(self.data.n[ks],
-                                                jnp.float32),
+                            weights=to_device(self.data.n[ks],
+                                              jnp.float32),
                             staleness=jnp.zeros((len(sub),), jnp.int32))
                     self._finish_round(
                         rounds, curve, global_params,

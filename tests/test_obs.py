@@ -8,6 +8,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import obs
@@ -27,7 +28,7 @@ def _no_global_tracer():
 
 
 def test_disabled_span_is_shared_noop():
-    assert not obs.enabled()
+    assert not obs.enabled() and not obs.syncing()
     s1 = obs.span("anything", foo=1)
     s2 = obs.span("else")
     assert s1 is s2                      # one shared singleton, no alloc
@@ -104,6 +105,36 @@ def test_nested_spans_record_depth_and_duration():
     assert inner["ts_us"] + inner["dur_us"] <= \
         outer["ts_us"] + outer["dur_us"] + 1.0
     assert inner["t_wall"] >= outer["t_wall"] - 1e-3
+
+
+def test_spans_record_parent_and_one_clock():
+    """Each event names the span that enclosed it (by open-order id), and
+    its wall time is the tracer's origin plus its offset."""
+    with obs.tracing() as t:
+        with obs.span("a"):
+            with obs.span("b"):
+                with obs.span("c"):
+                    pass
+            with obs.span("d"):
+                pass
+        with obs.span("e"):
+            pass
+    ev = {e["name"]: e for e in t.events}
+    assert [ev[n]["id"] for n in "abcde"] == [0, 1, 2, 3, 4]
+    assert [ev[n]["parent"] for n in "abcde"] == [None, 0, 1, 0, None]
+    for e in t.events:
+        assert e["t_wall"] == pytest.approx(t.t0_wall + e["ts_us"] / 1e6,
+                                            rel=0, abs=1e-9)
+
+
+@pytest.mark.parametrize("sync", [True, False])
+def test_sync_flag_and_syncing(sync):
+    assert not obs.syncing()
+    with obs.tracing(sync=sync) as t:
+        assert t.sync is sync and obs.syncing() is sync
+    assert obs.enable(sync=sync).sync is sync and obs.syncing() is sync
+    obs.disable()
+    assert not obs.syncing()
 
 
 def test_span_set_and_error_annotation():
@@ -293,9 +324,42 @@ def _tiny_sim():
                             cfg=cfg, access=aw)
 
 
+def _train_sim(execution="host", alg="fedavg", **cfg_kw):
+    """A trained run small enough for the CPU: 6 satellites, 3 rounds,
+    shards of at most 40 samples, 4 local steps."""
+    from repro.core import ALGORITHMS
+    from repro.data import synth_femnist
+    from repro.orbits import (
+        WalkerStar,
+        compute_access_windows,
+        station_subnetwork,
+    )
+    from repro.sim import ConstellationSim, SimConfig
+
+    c, st = WalkerStar(2, 3), station_subnetwork(3)
+    horizon = 4 * 86400.0
+    cfg = dict(max_rounds=3, horizon_s=horizon, eval_every=2, max_steps=4,
+               clients_per_round=5, seed=0)
+    cfg.update(cfg_kw)
+    return ConstellationSim(
+        c, st, ALGORITHMS[alg], cfg=SimConfig(**cfg),
+        access=compute_access_windows(c, st, horizon_s=horizon),
+        data=synth_femnist(c.n_sats, seed=0, min_samples=20,
+                           max_samples=40, eval_samples=8),
+        workload="femnist_mlp", execution=execution)
+
+
+def _same_result(a, b) -> bool:
+    return (a.rounds == b.rounds and a.accuracy_curve == b.accuracy_curve
+            and all(np.array_equal(x, y) for x, y in zip(
+                jax.tree.leaves(a.final_params),
+                jax.tree.leaves(b.final_params), strict=True)))
+
+
 def test_traced_run_bitwise_identical_and_instrumented():
-    """Tracing observes walls only: simulated results are identical, and
-    the acceptance span chain (round -> eval) + counters are recorded."""
+    """Tracing observes walls only: simulated results are identical, with
+    or without waiting on the device, and the acceptance span chain
+    (run -> round -> eval) + counters are recorded."""
     base = _tiny_sim().run()
     with obs.tracing() as t:
         traced = _tiny_sim().run()
@@ -306,12 +370,21 @@ def test_traced_run_bitwise_identical_and_instrumented():
         [r.participants for r in base.rounds]
     assert traced.accuracy_curve == base.accuracy_curve
     names = {ev["name"] for ev in t.events}
-    assert {"sim.round", "sim.select", "sim.eval"} <= names
+    assert {"sim.run", "sim.round", "sim.select", "sim.eval"} <= names
     assert s["counters"]["sim.rounds"] == 3
     assert s["counters"]["sim.evals"] == 2   # eval_every=2 over 3 rounds
-    # round spans enclose their select/eval children
+    # one run span encloses the rounds, which enclose select/eval
+    run, = [ev for ev in t.events if ev["name"] == "sim.run"]
     rounds = [ev for ev in t.events if ev["name"] == "sim.round"]
-    assert all(ev["depth"] == 0 for ev in rounds)
+    assert run["depth"] == 0 and run["parent"] is None
+    assert all(ev["depth"] == 1 and ev["parent"] == run["id"]
+               for ev in rounds)
+    # a trained run, untraced / traced blocking / traced not blocking
+    sim = _train_sim()
+    off = sim.run()
+    for sync in (True, False):
+        with obs.tracing(sync=sync):
+            assert _same_result(sim.run(), off), sync
 
 
 def test_traced_jit_call_flags_every_compile():
@@ -356,3 +429,251 @@ def test_mesh_fedbuff_flags_its_retraces():
              if ev["name"] == "sim.client_train"]
     compiles = sum(f._cache_size() for f in sim._mesh_steps.values())
     assert sum(flags) == compiles > len(sim._mesh_steps)
+
+
+def test_spans_on_the_profiler_host_plane(tmp_path):
+    """Under the profiler, a traced run's spans are annotations on the
+    host plane, nested there as the tracer recorded them."""
+    from jax.profiler import ProfileData
+
+    sim = _train_sim()
+    sim.run()                                   # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.tracing(sync=False) as t:
+            sim.run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = tmp_path.glob("**/*.xplane.pb")
+    host = [p for p in ProfileData.from_file(str(path)).planes
+            if p.name == "/host:CPU"][0]
+    seen = {}
+    for line in host.lines:
+        for ev in line.events:
+            if ev.name.startswith("sim."):
+                seen.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns, line.name))
+    ours = {}
+    for ev in t.events:
+        ours.setdefault(ev["name"], []).append(ev)
+    assert {"sim.run", "sim.round", "sim.client_train"} <= set(seen)
+    assert sorted(seen) == sorted(ours)
+    where = {}                                  # tracer id -> xplane span
+    for name, evs in ours.items():
+        assert len(seen[name]) == len(evs), name
+        for ev, iv in zip(sorted(evs, key=lambda e: e["ts_us"]),
+                          sorted(seen[name])):
+            where[ev["id"]] = iv
+    assert len({iv[2] for iv in where.values()}) == 1    # one thread
+    for ev in t.events:
+        if ev["parent"] is not None:
+            (s0, e0, _), (s1, e1, _) = where[ev["id"]], where[ev["parent"]]
+            assert s1 <= s0 and e0 <= e1, ev["name"]
+    train = [ev for ev in t.events if ev["name"] == "sim.client_train"]
+    by_id = {ev["id"]: ev for ev in t.events}
+    assert train and all(
+        by_id[by_id[ev["parent"]]["parent"]]["name"] == "sim.run"
+        and by_id[ev["parent"]]["name"] == "sim.round" for ev in train)
+
+
+# ------------------------------------------------ host-device traffic --
+
+ROW = 28 * 28 * 1 * 4      # bytes of one FEMNIST sample on the device
+
+
+def _eval_slots(sim, t: float) -> int:
+    """Clients in the evaluation batch at `t`: the evaluation stage's
+    selection (or the first clients when it selects none), padded to a
+    power of two."""
+    c = min(sim.cfg.clients_per_round, sim.constellation.n_sats)
+    plans = sim.alg.selector.select(
+        sim.aw, t, range(sim.constellation.n_sats), c, sim.alg.strategy,
+        sim.hw, sim.alg.local_epochs, sim.alg.min_epochs, plan=sim.plan)
+    m = len(plans) or min(c, sim.data.n_clients)
+    return 1 << (m - 1).bit_length()
+
+
+def _eval_traffic(sim, res) -> tuple[int, int]:
+    """(bytes uploaded, host syncs) of a run's evaluations: eval shards,
+    labels and counts per padded slot; one accuracy read each."""
+    per_slot = sim.data.x_eval.shape[1] * (ROW + 4) + 4
+    return (sum(_eval_slots(sim, t) * per_slot
+                for _, t, _ in res.accuracy_curve), len(res.accuracy_curve))
+
+
+def _loop_traffic(sim, res, slots=lambda n: n) -> tuple[int, int]:
+    """(bytes uploaded, host syncs) a loop run's records imply: per round
+    each of `slots(n)` client slots uploads its shard, labels, sample
+    count and step count, and the n participants their aggregation
+    weight, and their staleness where it comes from the host (FedBuff;
+    the barrier's zeros are made on the device); then the evaluations and
+    the final model's read."""
+    per_slot = sim.data.x.shape[1] * (ROW + 4) + 4 + 4
+    per_client = 4 if sim.alg.synchronous else 8
+    h2d = sum(slots(len(rec.participants)) * per_slot
+              + per_client * len(rec.participants) for rec in res.rounds)
+    eval_h2d, evals = _eval_traffic(sim, res)
+    return h2d + eval_h2d, evals + 1
+
+
+@pytest.mark.parametrize("alg", ["fedavg", "fedbuff"])
+def test_traffic_counters_match_the_schedule(alg):
+    sim = _train_sim(alg=alg, record_params=True)
+    with obs.tracing(sync=False) as t:
+        res = sim.run()
+    h2d, syncs = _loop_traffic(sim, res)
+    syncs += len(res.rounds)                 # record_params reads each
+    model = 4 * sum(np.size(a) for a in jax.tree.leaves(res.final_params))
+    c = t.counters
+    assert res.rounds and c["sim.h2d_bytes"] == h2d
+    assert c["sim.host_syncs"] == syncs
+    assert c["sim.d2h_bytes"] == 4 * len(res.accuracy_curve) + model * (
+        len(res.rounds) + 1)
+    run, = [ev for ev in t.events if ev["name"] == "sim.run"]
+    assert run["args"]["h2d_bytes"] == h2d
+    assert run["args"]["host_syncs"] == syncs
+    assert run["args"]["d2h_bytes"] == c["sim.d2h_bytes"]
+
+
+def test_batched_traffic_counters_match_the_schedule():
+    from repro.sim import ConstellationSim
+    from repro.sim.batched import BatchedSweep
+
+    sims = [_train_sim(), _train_sim(eval_every=1, max_rounds=2)]
+    with obs.tracing(sync=False) as t:
+        results = BatchedSweep(sims).run()
+    R = max(len(r.rounds) for r in results)
+    C = ConstellationSim._bound(
+        [max(len(rec.participants) for r in results for rec in r.rounds)])
+    B, N = len(sims), sims[0].data.x.shape[1]
+    # per lockstep round: shards and labels, counts, steps, rng keys (2
+    # words), weights and staleness for every (scenario, slot); once:
+    # server learning rates and proximal terms
+    h2d = R * B * C * (N * (ROW + 4) + 4 + 4 + 8 + 4 + 4) + 2 * 4 * B
+    syncs = 0
+    for sim, res in zip(sims, results):
+        eval_h2d, evals = _eval_traffic(sim, res)
+        h2d += eval_h2d
+        syncs += len(res.rounds) + evals + 1   # key splits, evals, final
+    assert t.counters["sim.h2d_bytes"] == h2d
+    assert t.counters["sim.host_syncs"] == syncs
+    assert any(ev["name"] == "sim.batched.assemble" for ev in t.events)
+
+
+MESH_TRAFFIC = r"""
+import os
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=4")
+import jax
+assert jax.device_count() == 4
+from repro import obs
+from tests.test_obs import _loop_traffic, _train_sim
+
+sim = _train_sim(execution="mesh", clients_per_round=6)
+with obs.tracing(sync=False) as t:
+    res = sim.run()
+size = lambda n: max(1, min(4, n))
+h2d, syncs = _loop_traffic(sim, res,
+                           slots=lambda n: -(-n // size(n)) * size(n))
+assert any(len(r.participants) > 4 for r in res.rounds), res.rounds
+assert t.counters["sim.h2d_bytes"] == h2d, (t.counters, h2d)
+assert t.counters["sim.host_syncs"] == syncs, (t.counters, syncs)
+print("MESH_TRAFFIC_OK")
+"""
+
+
+def test_mesh_traffic_counters_match_the_schedule():
+    """The mesh path on four forced CPU devices uploads every padded pod
+    slot."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    out = subprocess.run([sys.executable, "-c", MESH_TRAFFIC], env=env,
+                         capture_output=True, text=True, timeout=600,
+                         cwd=root)
+    assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
+    assert "MESH_TRAFFIC_OK" in out.stdout
+
+
+@pytest.fixture
+def strict_reads(monkeypatch):
+    """Device-to-host reads outside an explicit `jax.device_get` raise.
+
+    On an accelerator `jax.transfer_guard_device_to_host("disallow")`
+    does this. On the CPU a read copies nothing, so the guard never
+    fires there: the conversions an implicit read goes through are
+    wrapped to raise outside `device_get`'s own scope."""
+    from jax._src import array, config
+
+    state = config.guard_lib.thread_local_state
+
+    def strict(fn, name):
+        def read(*args, **kwargs):
+            if (args and isinstance(args[0], jax.Array)
+                    and not state().explicit_device_get):
+                raise AssertionError(f"implicit device read: {name}")
+            return fn(*args, **kwargs)
+        return read
+
+    for name in ("__float__", "__int__", "__bool__", "__index__", "item",
+                 "tolist", "__array__"):
+        monkeypatch.setattr(array.ArrayImpl, name,
+                            strict(getattr(array.ArrayImpl, name), name))
+    for name in ("asarray", "array"):
+        monkeypatch.setattr(np, name, strict(getattr(np, name), name))
+    with jax.transfer_guard_device_to_host("disallow"):
+        yield
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_no_implicit_device_reads(traced, monkeypatch, request):
+    """Every device-to-host read of a run is an explicit, counted
+    `to_host`; tracing with `sync=False` adds no read and never waits."""
+    from repro.sim.batched import BatchedSweep
+
+    sim, sweep = _train_sim(record_params=True), BatchedSweep([_train_sim()])
+    sim.run()                                # compile outside the guard
+    sweep.run()
+    request.getfixturevalue("strict_reads")
+
+    def no_wait(x):
+        raise AssertionError("block_until_ready with sync=False")
+
+    if traced:
+        monkeypatch.setattr(jax, "block_until_ready", no_wait)
+        with obs.tracing(sync=False) as t:
+            sim.run()
+            sweep.run()
+        assert t.counters["sim.host_syncs"] > 0
+    else:
+        sim.run()
+        sweep.run()
+
+
+def test_traced_jit_call_reads_the_cache_only_while_tracing():
+    from repro.sim.engine import traced_jit_call
+
+    f = jax.jit(lambda x: x + 1)
+    reads = []
+
+    class Probe:
+        def __call__(self, *a):
+            return f(*a)
+
+        def _cache_size(self):
+            reads.append(1)
+            return f._cache_size()
+
+    with obs.span("sim.client_train") as sp:
+        traced_jit_call(sp, Probe(), jnp.ones(2))
+    assert reads == []
+    with obs.tracing(sync=False) as t:
+        with obs.span("sim.client_train") as sp:
+            traced_jit_call(sp, Probe(), jnp.ones(3))
+    assert len(reads) == 2 and t.events[0]["args"]["jit_compile"]
