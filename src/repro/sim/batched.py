@@ -24,8 +24,10 @@ in two phases:
   2. **On-device batched rounds** (training phase, `cfg.train=True`).
      Per-scenario init params are stacked along a new leading scenario
      axis; each round gathers a rectangular (scenario, client) slab of
-     federated data shards, steps, weights, staleness, anchors and RNG
-     keys from the schedule and dispatches ONE jitted
+     federated data shards on the device, from the rows the schedule
+     names, uploaded once per call (`engine.resident_shards`), builds
+     steps, weights, staleness, anchors and RNG keys from the schedule,
+     and dispatches ONE jitted
      `vmap(vmapped_client_update)` — the same per-client function object
      the engine and `launch.fl_round` use — followed by one
      `vmap(weighted_delta_update)` masked aggregation (`server_lr=1`,
@@ -73,6 +75,7 @@ from repro.sim.engine import (
     ConstellationSim,
     buffer_weights,
     client_steps,
+    resident_shards,
     run_span,
     sync_round_metrics,
     to_device,
@@ -469,8 +472,21 @@ class BatchedSweep:
                      for rec in planned[i].rounds), default=1)
         C = ConstellationSim._bound([c_max])
         N = max(sims[i].data.x.shape[1] for i in fed)
-        x0 = sims[fed[0]].data.x
-        y0 = sims[fed[0]].data.y
+        # The training shards go up once for this call: of each distinct
+        # dataset array, the rows some planned round names. `place` maps a
+        # dataset to its rows and where they start on the device.
+        named: dict[int, tuple] = {}
+        for i in fed:
+            data = sims[i].data
+            _, used = named.setdefault(id(data.x), (data, set()))
+            used.update(k for rec in planned[i].rounds
+                        for k in rec.participants)
+        parts = [(d, np.array(sorted(used)))
+                 for d, used in named.values() if used]
+        place, n_rows = {}, 0
+        for d, rows in parts:
+            place[id(d.x)] = (rows, n_rows)
+            n_rows += len(rows)
 
         # Per-(batch,round) max staleness → how far back anchors reach;
         # a suffix-min over rounds bounds the history the executor keeps.
@@ -498,13 +514,15 @@ class BatchedSweep:
             active = [b for b in range(B) if r < n_rounds[b]]
             with span("sim.batched.assemble", round=r,
                       scenarios=len(active)):
+                if r == 0:
+                    gather = resident_shards(parts, N)
                 steps = np.zeros((B, C), np.int32)
                 w = np.zeros((B, C), np.float32)
                 stale = np.zeros((B, C), np.int32)
-                nv = np.zeros((B, C), np.int32)
                 vs = np.full((B, C), r, np.int64)
-                x = np.zeros((B, C, N) + x0.shape[2:], x0.dtype)
-                y = np.zeros((B, C, N), y0.dtype)
+                # Device rows of the slab; row n_rows, past the end, reads
+                # as zeros, as a finished scenario's lanes always have.
+                rows_bc = np.full((B, C), n_rows, np.int32)
                 rngs = np.zeros((B, C, 2), np.uint32)
                 for b in active:
                     sim = sims[fed[b]]
@@ -528,10 +546,8 @@ class BatchedSweep:
                             sim.alg.strategy.max_staleness)
                         stale[b, :n] = st
                         vs[b, :n] = r - st
-                    nb = data.x.shape[1]
-                    x[b, :, :nb] = data.x[ks_p]
-                    y[b, :, :nb] = data.y[ks_p]
-                    nv[b] = data.n[ks_p]
+                    rows, start = place[id(data.x)]
+                    rows_bc[b] = start + np.searchsorted(rows, ks_p)
                     rr = to_host(jax.random.split(subs[b][r], n))
                     rngs[b, :n] = rr
                     if C > n:
@@ -556,10 +572,10 @@ class BatchedSweep:
                 with span("sim.client_train", mode="batched",
                           scenarios=len(active), step_bound=bound) as sp:
                     keys = to_device(rngs)
+                    x, y, nv = gather(rows_bc)
                     out = traced_jit_call(
-                        sp, update, anchors, anchors, to_device(x),
-                        to_device(y), to_device(nv), to_device(steps),
-                        prox, keys)
+                        sp, update, anchors, anchors, x, y, nv,
+                        to_device(steps), prox, keys)
                     if self.codec.lossy:
                         # Same per-client codec round-trip as the loop
                         # engine (same rng keys: split(sub, n) rows), so

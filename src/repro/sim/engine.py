@@ -107,7 +107,8 @@ def client_steps(n_k: int, epochs: int, batch_size: int,
     return int(np.clip(epochs * spe, 1, max_steps))
 
 
-TRAFFIC_COUNTERS = ("sim.h2d_bytes", "sim.d2h_bytes", "sim.host_syncs")
+TRAFFIC_COUNTERS = ("sim.h2d_bytes", "sim.d2h_bytes", "sim.host_syncs",
+                    "sim.gathered_rows")
 
 
 def to_device(x, dtype=None) -> jax.Array:
@@ -118,6 +119,46 @@ def to_device(x, dtype=None) -> jax.Array:
     if obs_enabled() and not isinstance(x, jax.Array):
         count("sim.h2d_bytes", out.nbytes)
     return out
+
+
+@jax.jit
+def _take_rows(shards, idx):
+    return tuple(a.at[idx].get(mode="fill", fill_value=0) for a in shards)
+
+
+def resident_shards(parts, samples: int | None = None):
+    """Upload client training shards once; serve their rows by index.
+
+    `parts` is a `FederatedDataset` (all of its clients) or a list of
+    `(dataset, rows)` pairs, whose rows are stacked in order. Each part's
+    `x`, `y` and `n` go up through `to_device`, so they are counted in
+    `sim.h2d_bytes`; a part with fewer samples than `samples` (default:
+    the most of any part) is zero-padded on the device.
+
+    The returned `gather(idx) -> (x, y, n)` uploads only the int32 row
+    numbers `idx`, of any shape, and takes those rows on the device. A
+    row number past the last row reads as zeros. Each call counts
+    `idx.size` in `sim.gathered_rows`.
+    """
+    if isinstance(parts, FederatedDataset):
+        parts = [(parts, slice(None))]
+    samples = samples or max(d.x.shape[1] for d, _ in parts)
+    cols = []
+    for d, rows in parts:
+        x, y = to_device(d.x[rows]), to_device(d.y[rows])
+        pad = samples - x.shape[1]
+        if pad:
+            x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+            y = jnp.pad(y, [(0, 0), (0, pad)])
+        cols.append((x, y, to_device(d.n[rows])))
+    shards = tuple(c[0] if len(c) == 1 else jnp.concatenate(c)
+                   for c in zip(*cols))
+
+    def gather(idx):
+        count("sim.gathered_rows", np.size(idx))
+        return _take_rows(shards, to_device(idx, jnp.int32))
+
+    return gather
 
 
 def to_host(tree):
@@ -140,9 +181,10 @@ def to_host(tree):
 def run_span(**args):
     """The `sim.run` span around one whole run (`ConstellationSim.run`,
     `BatchedSweep.run`). On a clean exit it carries the run's host-device
-    traffic as args `h2d_bytes`, `d2h_bytes` and `host_syncs`: how much
-    each of `TRAFFIC_COUNTERS` grew during the run (counters are global to
-    the tracer; the args price one run from its span alone)."""
+    traffic as args `h2d_bytes`, `d2h_bytes`, `host_syncs` and
+    `gathered_rows`: how much each of `TRAFFIC_COUNTERS` grew during the
+    run (counters are global to the tracer; the args price one run from
+    its span alone)."""
     tracer = get_tracer()
     with span("sim.run", **args) as sp:
         if tracer is None:
@@ -350,6 +392,7 @@ class ConstellationSim:
                     "staleness-discounted-delta family; mesh execution "
                     "would bypass it — run with execution='host'")
         self._params_hist: list = []
+        self._shards = None           # `resident_shards` during a run
         if self.cfg.train:
             if self.data is None:
                 self.data = self.workload.make_data(constellation.n_sats,
@@ -404,7 +447,10 @@ class ConstellationSim:
             if K < 2:
                 # A single satellite cannot federate (heatmap top-left = 0).
                 return self._result([], [], None)
-            return self._run_events()
+            try:
+                return self._run_events()
+            finally:
+                self._shards = None   # resident for this run only
 
     # ------------------------------------------------------------------ #
     def _steps_for(self, k: int, epochs: int) -> int:
@@ -419,6 +465,10 @@ class ConstellationSim:
                      rng, anchors=None):
         """Train-batch assembly + vmapped ClientUpdate for `ks`.
 
+        The run's training shards go up to the device at its first trained
+        round (`resident_shards`); each round then uploads only the
+        participants' row numbers and gathers their shards there.
+
         `anchors` is None for the synchronous barrier (everyone anchors on
         the current global model, broadcast once) or a stacked pytree of
         per-client anchor versions (FedBuff). Returns the stacked client
@@ -426,9 +476,9 @@ class ConstellationSim:
         """
         steps_np = [self._steps_for(k, e) for k, e in zip(ks, epochs)]
         steps = to_device(steps_np, jnp.int32)
-        x = to_device(self.data.x[ks])
-        y = to_device(self.data.y[ks])
-        n = to_device(self.data.n[ks])
+        if self._shards is None:      # the run's first trained round
+            self._shards = resident_shards(self.data)
+        x, y, n = self._shards(ks)
         anchored = anchors is not None
         if anchored:
             params0 = anchors

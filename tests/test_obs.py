@@ -324,7 +324,7 @@ def _tiny_sim():
                             cfg=cfg, access=aw)
 
 
-def _train_sim(execution="host", alg="fedavg", **cfg_kw):
+def _train_sim(execution="host", alg="fedavg", data=None, **cfg_kw):
     """A trained run small enough for the CPU: 6 satellites, 3 rounds,
     shards of at most 40 samples, 4 local steps."""
     from repro.core import ALGORITHMS
@@ -344,8 +344,8 @@ def _train_sim(execution="host", alg="fedavg", **cfg_kw):
     return ConstellationSim(
         c, st, ALGORITHMS[alg], cfg=SimConfig(**cfg),
         access=compute_access_windows(c, st, horizon_s=horizon),
-        data=synth_femnist(c.n_sats, seed=0, min_samples=20,
-                           max_samples=40, eval_samples=8),
+        data=data or synth_femnist(c.n_sats, seed=0, min_samples=20,
+                                   max_samples=40, eval_samples=8),
         workload="femnist_mlp", execution=execution)
 
 
@@ -501,19 +501,38 @@ def _eval_traffic(sim, res) -> tuple[int, int]:
                 for _, t, _ in res.accuracy_curve), len(res.accuracy_curve))
 
 
-def _loop_traffic(sim, res, slots=lambda n: n) -> tuple[int, int]:
-    """(bytes uploaded, host syncs) a loop run's records imply: per round
-    each of `slots(n)` client slots uploads its shard, labels, sample
-    count and step count, and the n participants their aggregation
-    weight, and their staleness where it comes from the host (FedBuff;
-    the barrier's zeros are made on the device); then the evaluations and
-    the final model's read."""
-    per_slot = sim.data.x.shape[1] * (ROW + 4) + 4 + 4
+def _shard_bytes(data) -> int:
+    """Bytes on the device of one client's training shard, labels and
+    sample count."""
+    return data.x.shape[1] * (ROW + 4) + 4
+
+
+def _loop_traffic(sim, res, slots=None) -> tuple[int, int]:
+    """(bytes uploaded, host syncs) a loop run's records imply.
+
+    The host loop uploads every client's shard, labels and sample count
+    once per trained run, then per round each participant's row number
+    and step count. The mesh path (`slots`) uploads per round, for each
+    of `slots(n)` padded pod slots, a shard, labels, sample count and
+    step count. Both upload per round the n participants' aggregation
+    weights, and their staleness where it comes from the host (FedBuff;
+    the barrier's zeros are made on the device); then the evaluations
+    and the final model's read."""
+    shard = _shard_bytes(sim.data)
     per_client = 4 if sim.alg.synchronous else 8
-    h2d = sum(slots(len(rec.participants)) * per_slot
-              + per_client * len(rec.participants) for rec in res.rounds)
+    if slots is None:
+        h2d = sim.data.n_clients * shard if res.rounds else 0
+        h2d += sum((4 + 4 + per_client) * len(rec.participants)
+                   for rec in res.rounds)
+    else:
+        h2d = sum(slots(len(rec.participants)) * (shard + 4)
+                  + per_client * len(rec.participants) for rec in res.rounds)
     eval_h2d, evals = _eval_traffic(sim, res)
     return h2d + eval_h2d, evals + 1
+
+
+def _gathered_rows(res) -> int:
+    return sum(len(rec.participants) for rec in res.rounds)
 
 
 @pytest.mark.parametrize("alg", ["fedavg", "fedbuff"])
@@ -529,35 +548,154 @@ def test_traffic_counters_match_the_schedule(alg):
     assert c["sim.host_syncs"] == syncs
     assert c["sim.d2h_bytes"] == 4 * len(res.accuracy_curve) + model * (
         len(res.rounds) + 1)
+    assert c["sim.gathered_rows"] == _gathered_rows(res)
     run, = [ev for ev in t.events if ev["name"] == "sim.run"]
     assert run["args"]["h2d_bytes"] == h2d
     assert run["args"]["host_syncs"] == syncs
     assert run["args"]["d2h_bytes"] == c["sim.d2h_bytes"]
+    assert run["args"]["gathered_rows"] == _gathered_rows(res)
+
+
+def _batched_traffic(sims, results) -> tuple[int, int, int]:
+    """(bytes uploaded, host syncs, rows gathered) of a trained batched
+    sweep of synchronous scenarios. Once: of each distinct dataset
+    array, the shard, labels and sample count of every client some
+    round names, and the server learning rates and proximal terms. Per
+    lockstep round, for every (scenario, slot): its row number, steps,
+    rng key (2 words), weight and staleness, and the gather of its row.
+    Then the evaluations, one key split per scenario and round, and the
+    final models' reads."""
+    from repro.sim import ConstellationSim
+
+    R = max(len(r.rounds) for r in results)
+    C = ConstellationSim._bound(
+        [max(len(rec.participants) for r in results for rec in r.rounds)])
+    B = len(sims)
+    named = {}
+    for sim, res in zip(sims, results):
+        named.setdefault(id(sim.data.x), (sim.data, set()))[1].update(
+            k for rec in res.rounds for k in rec.participants)
+    h2d = sum(len(used) * _shard_bytes(d) for d, used in named.values())
+    h2d += R * B * C * (4 + 4 + 8 + 4 + 4) + 2 * 4 * B
+    syncs = 0
+    for sim, res in zip(sims, results):
+        eval_h2d, evals = _eval_traffic(sim, res)
+        h2d += eval_h2d
+        syncs += len(res.rounds) + evals + 1
+    return h2d, syncs, R * B * C
 
 
 def test_batched_traffic_counters_match_the_schedule():
-    from repro.sim import ConstellationSim
     from repro.sim.batched import BatchedSweep
 
     sims = [_train_sim(), _train_sim(eval_every=1, max_rounds=2)]
     with obs.tracing(sync=False) as t:
         results = BatchedSweep(sims).run()
-    R = max(len(r.rounds) for r in results)
-    C = ConstellationSim._bound(
-        [max(len(rec.participants) for r in results for rec in r.rounds)])
-    B, N = len(sims), sims[0].data.x.shape[1]
-    # per lockstep round: shards and labels, counts, steps, rng keys (2
-    # words), weights and staleness for every (scenario, slot); once:
-    # server learning rates and proximal terms
-    h2d = R * B * C * (N * (ROW + 4) + 4 + 4 + 8 + 4 + 4) + 2 * 4 * B
-    syncs = 0
-    for sim, res in zip(sims, results):
-        eval_h2d, evals = _eval_traffic(sim, res)
-        h2d += eval_h2d
-        syncs += len(res.rounds) + evals + 1   # key splits, evals, final
+    h2d, syncs, rows = _batched_traffic(sims, results)
     assert t.counters["sim.h2d_bytes"] == h2d
     assert t.counters["sim.host_syncs"] == syncs
+    assert t.counters["sim.gathered_rows"] == rows
     assert any(ev["name"] == "sim.batched.assemble" for ev in t.events)
+
+
+def test_batched_sweep_uploads_a_shared_dataset_once():
+    """Scenarios whose datasets share their arrays share the rows on the
+    device: the union of the clients their rounds name goes up once."""
+    import dataclasses
+
+    from repro.sim.batched import BatchedSweep
+
+    first = _train_sim()
+    sims = [first, _train_sim(eval_every=1, max_rounds=2,
+                              data=dataclasses.replace(first.data))]
+    with obs.tracing(sync=False) as t:
+        results = BatchedSweep(sims).run()
+    h2d, _, rows = _batched_traffic(sims, results)
+    assert t.counters["sim.h2d_bytes"] == h2d
+    assert t.counters["sim.gathered_rows"] == rows
+    union = {k for r in results for rec in r.rounds for k in rec.participants}
+    twice = sum(len({k for rec in r.rounds for k in rec.participants})
+                for r in results)
+    assert len(union) < twice     # the two scenarios name common clients
+
+
+def test_timing_only_runs_upload_no_shards():
+    from repro.sim.batched import BatchedSweep
+
+    sim = _train_sim(train=False)
+    with obs.tracing(sync=False) as t:
+        assert sim.run().rounds
+        assert BatchedSweep([_train_sim(train=False)]).run()[0].rounds
+    assert t.counters.get("sim.h2d_bytes", 0) == 0
+    assert t.counters.get("sim.gathered_rows", 0) == 0
+
+
+def test_each_run_uploads_its_own_shards():
+    """The shards stay on the device for one `run()` only: a second run
+    of the same sim uploads them again, and none are kept between."""
+    sim = _train_sim()
+    with obs.tracing(sync=False) as t:
+        first = sim.run()
+        assert sim._shards is None
+        second = sim.run()
+    assert sim._shards is None and _same_result(first, second)
+    h2d, _ = _loop_traffic(sim, first)
+    runs = [ev["args"] for ev in t.events if ev["name"] == "sim.run"]
+    assert [a["h2d_bytes"] for a in runs] == [h2d, h2d]
+    assert [a["gathered_rows"] for a in runs] == [_gathered_rows(first)] * 2
+
+
+def _gather_case(case):
+    """(parts, row numbers, the x, y, n slab the host used to build for
+    them): `resident_shards`' input, its gather's, and what it replaces."""
+    from repro.core.workload import get_workload
+    from repro.data import synth_femnist
+
+    if case in ("host_loop", "lm_tokens"):
+        d = (get_workload("lm_tiny").make_data(4, seed=0)
+             if case == "lm_tokens"
+             else synth_femnist(6, seed=0, min_samples=20, max_samples=40,
+                                eval_samples=8))
+        ks = [3, 1, 3]
+        return d, ks, (d.x[ks], d.y[ks], d.n[ks])
+    # The batched sweep's slab: datasets of different sample counts, the
+    # padding slots repeating the first client, a finished scenario's
+    # lane all zeros.
+    a = synth_femnist(6, seed=0, min_samples=20, max_samples=40,
+                      eval_samples=8)
+    b = synth_femnist(5, seed=1, min_samples=10, max_samples=24,
+                      eval_samples=8)
+    parts = [(a, np.array([0, 2, 5])), (b, np.array([1, 4]))]
+    lanes = [(a, [5, 0, 5, 5]), (b, [4, 1, 4, 4]), None]
+    idx = np.array([[2, 0, 2, 2], [4, 3, 4, 4], [5, 5, 5, 5]], np.int32)
+    x = np.zeros((3, 4, 40) + a.x.shape[2:], a.x.dtype)
+    y = np.zeros((3, 4, 40), a.y.dtype)
+    n = np.zeros((3, 4), np.int32)
+    for i, lane in enumerate(lanes):
+        if lane is not None:
+            d, ks = lane
+            x[i, :, :d.x.shape[1]] = d.x[ks]
+            y[i, :, :d.x.shape[1]] = d.y[ks]
+            n[i] = d.n[ks]
+    return parts, idx, (x, y, n)
+
+
+@pytest.mark.parametrize("case", ["host_loop", "lm_tokens", "batched_slab"])
+def test_device_gather_is_the_host_slab(case):
+    """The rows gathered on the device are, bit for bit, the slab the
+    host built and uploaded: same shape, dtype and values, zeros past
+    each dataset's samples."""
+    from repro.sim.engine import resident_shards, to_device
+
+    parts, idx, host = _gather_case(case)
+    with obs.tracing(sync=False) as t:
+        got = resident_shards(parts)(idx)
+    assert t.counters["sim.gathered_rows"] == np.size(idx)
+    for g, want in zip(got, host, strict=True):
+        want = np.asarray(to_device(want))
+        g = jax.device_get(g)
+        assert (g.shape, g.dtype) == (want.shape, want.dtype)
+        assert g.tobytes() == want.tobytes()
 
 
 MESH_TRAFFIC = r"""
@@ -578,6 +716,7 @@ h2d, syncs = _loop_traffic(sim, res,
 assert any(len(r.participants) > 4 for r in res.rounds), res.rounds
 assert t.counters["sim.h2d_bytes"] == h2d, (t.counters, h2d)
 assert t.counters["sim.host_syncs"] == syncs, (t.counters, syncs)
+assert "sim.gathered_rows" not in t.counters, t.counters
 print("MESH_TRAFFIC_OK")
 """
 
