@@ -12,12 +12,12 @@ call); the window then calls the same object again and again.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import time
 
 import numpy as np
 
 from bench.reference import Update, client_steps
-from bench.traffic.femnist import generate
 
 TIMING_FIELDS = ("t_start", "t_end", "participants", "epochs", "idle_s",
                  "compute_s", "comm_s", "relays", "staleness", "relay_hops",
@@ -27,6 +27,14 @@ SEED_MOD = 2 ** 31 - 1      # the program's PRNGKey takes a 32-bit seed
 
 def program_seed(seed: int) -> int:
     return int(seed) % SEED_MOD
+
+
+def client_data(cfg: dict, mix: dict, n_clients: int, seed: int) -> dict:
+    """The clients' shards, from the generator that the configuration
+    names (`bench/traffic/<cfg["generator"]>.py`), given the seed, the
+    configuration and the mix's `data` block."""
+    gen = importlib.import_module("bench.traffic." + cfg["generator"])
+    return gen.generate(n_clients, seed, cfg, **mix["data"])
 
 
 class Cell:
@@ -56,7 +64,7 @@ class Cell:
         # the first rows of the largest one's data.
         t = time.perf_counter()
         k_max = max(c * s for c, s in shapes)
-        full = generate(k_max, self.seed, **mix["data"])
+        full = client_data(cfg, mix, k_max, self.seed)
         self.data = {}
         for c, s in shapes:
             self.data[(c, s)] = {k: v[:c * s] for k, v in full.items()}

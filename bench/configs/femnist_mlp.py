@@ -1,13 +1,16 @@
 """Plain reference of `femnist_mlp`: dense 784 -> 56 (ReLU) -> 47.
 
 Straightforward jax.numpy, no kernels and no batching over clients. It
-imports nothing of the program. Weights are He-normal, biases zero, drawn
+imports nothing of the program; its data loss is the benchmark's
+`softmax_cross_entropy`. Weights are He-normal, biases zero, drawn
 from the key in the order the published init draws them.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from bench.reference import softmax_cross_entropy
 
 
 def forward_flops(cfg: dict) -> int:
@@ -29,3 +32,7 @@ def apply(params: dict, x):
     h = x.reshape((x.shape[0], -1))
     h = jax.nn.relu(h @ params["fc1"]["w"] + params["fc1"]["b"])
     return h @ params["fc2"]["w"] + params["fc2"]["b"]
+
+
+def loss(params: dict, xb, yb):
+    return softmax_cross_entropy(apply(params, xb), yb)

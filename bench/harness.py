@@ -3,7 +3,9 @@ the check against the plain reference, and the result line.
 
 Everything a cell needs is found by name: its configuration in
 `bench/configs/<config>.json` with the plain model `<config>.py` beside
-it, its mix in `bench/traffic/<traffic>.json`, its limits in
+it (`init`, `apply`, the data loss `loss`, `forward_flops`), the data
+generator the configuration names in `bench/traffic/<generator>.py`, its
+mix in `bench/traffic/<traffic>.json`, its limits in
 `bench/limits/<cell>.json`, each metric's reader in
 `bench/metrics/<metric>.py`, and the peaks in `bench/peaks.json`.
 """

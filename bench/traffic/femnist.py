@@ -73,14 +73,21 @@ def _render(proto: np.ndarray, labels: np.ndarray, rng: np.random.Generator
     return np.clip(img, 0.0, 1.0)
 
 
-def generate(n_clients: int, seed: int, *, min_samples: int = 200,
-             max_samples: int = 350, eval_samples: int = 64,
-             dirichlet_alpha: float = 1.0) -> dict[str, np.ndarray]:
+def generate(n_clients: int, seed: int, cfg: dict, *,
+             min_samples: int = 200, max_samples: int = 350,
+             eval_samples: int = 64, dirichlet_alpha: float = 1.0
+             ) -> dict[str, np.ndarray]:
     """Stacked client shards, padded to `max_samples` rows.
 
     Returns x (K, N, 28, 28, 1), y (K, N), n (K,), and the held-out
-    x_eval, y_eval, n_eval, all as numpy arrays.
+    x_eval, y_eval, n_eval, all as numpy arrays. The configuration `cfg`
+    has to take these glyphs and classes.
     """
+    if cfg["classes"] != N_CLASSES or list(cfg["image"]) != [IMG, IMG, 1]:
+        raise ValueError(f"configuration {cfg['name']!r} takes "
+                         f"{cfg['image']} inputs of {cfg['classes']} classes;"
+                         f" this generator makes [{IMG}, {IMG}, 1] glyphs of "
+                         f"{N_CLASSES}")
     proto = class_prototypes()
     N = max_samples
     x = np.zeros((n_clients, N, IMG, IMG, 1), np.float32)
