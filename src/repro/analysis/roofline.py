@@ -32,8 +32,9 @@ def param_count(cfg) -> int:
         if cfg.mla is not None:
             m = cfg.mla
             qh = m.nope_head_dim + m.rope_head_dim
-            return (d * m.q_lora_rank + m.q_lora_rank * H * qh
-                    + d * (m.kv_lora_rank + m.rope_head_dim)
+            q = (d * H * qh if m.q_lora_rank is None
+                 else d * m.q_lora_rank + m.q_lora_rank * H * qh)
+            return (q + d * (m.kv_lora_rank + m.rope_head_dim)
                     + m.kv_lora_rank * H * (m.nope_head_dim + m.v_head_dim)
                     + H * m.v_head_dim * d)
         return d * H * hd + 2 * d * KV * hd + H * hd * d

@@ -14,6 +14,7 @@ ARCH_IDS = (
     "rwkv6-1.6b",
     "hymba-1.5b",
     "qwen1.5-110b",
+    "moonlight-16b-a3b",
     "femnist-47k",          # the paper's own client model
 )
 
@@ -28,6 +29,7 @@ _MODULES = {
     "rwkv6-1.6b": "rwkv6_1_6b",
     "hymba-1.5b": "hymba_1_5b",
     "qwen1.5-110b": "qwen1_5_110b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "femnist-47k": "femnist_47k",
 }
 

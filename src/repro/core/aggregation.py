@@ -37,10 +37,19 @@ def weighted_average(stacked: Pytree, weights: jax.Array,
     if use_kernel:
         from repro.kernels.ops import fedagg_pytree
         return fedagg_pytree(stacked, w)
-    def leaf_avg(x):
+    return fold_weighted(None, stacked, w)
+
+
+def fold_weighted(acc: Pytree | None, stacked: Pytree,
+                  w: jax.Array) -> Pytree:
+    """acc + sum_k w_k x_k over the leading (client) axis of each leaf of
+    `stacked`; `acc` None starts the sum. Folding a round's clients group
+    by group under its `normalized_weights` gives Eq. 1's average."""
+    def leaf(x):
         wb = w.reshape((-1,) + (1,) * (x.ndim - 1)).astype(x.dtype)
         return jnp.sum(wb * x, axis=0)
-    return jax.tree.map(leaf_avg, stacked)
+    total = jax.tree.map(leaf, stacked)
+    return total if acc is None else jax.tree.map(jnp.add, acc, total)
 
 
 def staleness_discount(staleness: jax.Array) -> jax.Array:

@@ -48,6 +48,8 @@ def make_client_update(
     max_steps: int = 64,
     *,
     loss_fn: Callable | None = None,
+    has_aux: bool = False,
+    unroll: bool = False,
 ) -> Callable:
     """Build the jitted ClientUpdate.
 
@@ -60,6 +62,16 @@ def make_client_update(
       x: (N, *sample_shape), y: (N,), n_valid: () int, steps: () int
       <= max_steps.
     `anchor` is the round's global model (the proximal anchor w_t).
+
+    With `has_aux`, `loss_fn` returns (loss, stats), a dict of arrays the
+    step counts (routing statistics, say), and the carried state is
+    (params, stats): `params0` becomes (params0, stats0) and so does the
+    return, each live step adding its stats (`zero_stats` gives stats0).
+
+    `unroll` writes the `max_steps` steps out in the program instead of a
+    loop, for a model whose copies fill the device: there XLA updates the
+    parameters in place from step to step, where a loop keeps both ends
+    of its carry (a few steps only: the program grows with each).
     """
     if loss_fn is None:
         if apply_fn is None:
@@ -67,33 +79,50 @@ def make_client_update(
         loss_fn = classification_loss(apply_fn)
 
     def prox_loss_fn(params, anchor, x, y, prox_mu):
-        data = loss_fn(params, x, y)
+        data, stats = loss_fn(params, x, y) if has_aux else (
+            loss_fn(params, x, y), None)
         sq = sum(jnp.sum((p - a) ** 2)
                  for p, a in zip(jax.tree.leaves(params),
                                  jax.tree.leaves(anchor)))
-        return data + 0.5 * prox_mu * sq
+        total = data + 0.5 * prox_mu * sq
+        return (total, stats) if has_aux else total
 
-    grad_fn = jax.grad(prox_loss_fn)
+    grad_fn = jax.grad(prox_loss_fn, has_aux=has_aux)
 
     def client_update(params0, anchor, x, y, n_valid, steps, prox_mu, rng):
         def body(i, carry):
-            params, rng = carry
+            state, rng = carry
+            params = state[0] if has_aux else state
             rng, sub = jax.random.split(rng)
             idx = jax.random.randint(sub, (batch_size,), 0, jnp.maximum(n_valid, 1))
-            g = grad_fn(params, anchor, x[idx], y[idx], prox_mu)
+            out = grad_fn(params, anchor, x[idx], y[idx], prox_mu)
+            g = out[0] if has_aux else out
             live = (i < steps).astype(jnp.float32)
             params = jax.tree.map(lambda p, gi: p - lr * live * gi, params, g)
+            if has_aux:
+                params = (params, jax.tree.map(
+                    lambda a, b: a + (i < steps).astype(b.dtype) * b,
+                    state[1], out[1]))
             return params, rng
 
-        params, _ = jax.lax.fori_loop(0, max_steps, body, (params0, rng))
+        params, _ = jax.lax.fori_loop(0, max_steps, body, (params0, rng),
+                                      unroll=unroll)
         return params
 
     return client_update
 
 
+def zero_stats(loss_fn: Callable, params, x, y, batch_size: int):
+    """Zeros shaped like the stats `loss_fn` returns for one minibatch of
+    one client's shard `x`, `y` (the `has_aux` client state's start)."""
+    shapes = jax.eval_shape(loss_fn, params, x[:batch_size], y[:batch_size])
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes[1])
+
+
 def vmapped_client_update(loss_fn: Callable, *, lr: float = 0.05,
                           batch_size: int = 32, max_steps: int = 64,
-                          anchored: bool = False) -> Callable:
+                          anchored: bool = False,
+                          has_aux: bool = False) -> Callable:
     """vmap ClientUpdate over a stacked client axis (not jitted).
 
     The one builder behind both execution paths: `sim.engine` jits it for
@@ -103,9 +132,10 @@ def vmapped_client_update(loss_fn: Callable, *, lr: float = 0.05,
 
     `anchored=False` broadcasts one shared anchor (the sync barrier);
     `anchored=True` maps per-client anchors (FedBuff historical versions).
+    `has_aux` as in `make_client_update`.
     """
     cu = make_client_update(loss_fn=loss_fn, lr=lr, batch_size=batch_size,
-                            max_steps=max_steps)
+                            max_steps=max_steps, has_aux=has_aux)
     axes = (0, 0 if anchored else None, 0, 0, 0, 0, None, 0)
     return jax.vmap(cu, in_axes=axes)
 

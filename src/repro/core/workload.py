@@ -46,6 +46,10 @@ it walks `ModelConfig.resolved_segments`, so mixed dense/MoE stacks
     Hymba-style hybrid) as sweepable constellation workloads. The MoE
     entry is the round-duration vs model-bytes crossover axis: all
     experts ride the wire, only `top_k` of them train per token.
+  * `moonlight_16b_a3b` — Moonlight-16B-A3B at its published widths,
+    cut to one chip's share of an expert-parallel deployment
+    (`ModelConfig.share`): 669 M parameters, whose round's clients do
+    not fit the device together, so the host loop streams them.
 """
 from __future__ import annotations
 
@@ -96,6 +100,11 @@ class Workload:
     #            a participation-masked psum (`launch.fl_round`).
     # `ConstellationSim(..., execution=...)` overrides per run.
     execution: str = "host"
+    # `loss_fn` returns (loss, stats): a dict of per-step counters (MoE
+    # routing, say) that the host loop's client program sums over its
+    # live steps and returns; they reach the tracer's counters under a
+    # syncing tracer. The mesh step and the batched sweep carry none.
+    loss_has_aux: bool = False
     mesh_axis: str = "pod"               # mesh axis carrying client pods
     # Batch-key ranks for the launch-style dict-batch contract (leading
     # dim sharded over `mesh_axis`); None = the engine's (x, y) schema.
@@ -232,7 +241,8 @@ def make_lm_evaluate(cfg) -> Callable:
 
     x: (K, N, S+1) int32 token rows; y is ignored (targets are x shifted);
     n_valid: (K,) valid-row counts. Mirrors `client.evaluate`'s contract
-    so the engine's padded-eval path works unchanged.
+    so the engine's padded-eval path works unchanged. Rows are mapped
+    over one at a time, so no more than one row's logits are held.
     """
     from repro.models.lm.transformer import forward_train
 
@@ -240,16 +250,17 @@ def make_lm_evaluate(cfg) -> Callable:
     def lm_evaluate(params, x, y, n_valid):
         del y
 
-        def one(xk):
-            logits, _ = forward_train(cfg, params, xk)
-            pred = jnp.argmax(logits[:, :-1, :], axis=-1)
-            hit = (pred == xk[:, 1:]).astype(jnp.float32)
-            return jnp.mean(hit, axis=-1)                    # (N,)
+        def one(row):
+            logits, _ = forward_train(cfg, params, row[None, :-1])
+            hit = jnp.argmax(logits[0], axis=-1) == row[1:]
+            return jnp.mean(hit.astype(jnp.float32))
 
-        correct = jax.vmap(one)(x)                           # (K, N)
-        mask = (jnp.arange(x.shape[1])[None, :]
-                < n_valid[:, None]).astype(jnp.float32)
-        return jnp.sum(correct * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        K, N = x.shape[:2]
+        correct = jax.lax.map(one, x.reshape((K * N,) + x.shape[2:]))
+        mask = (jnp.arange(N)[None, :] < n_valid[:, None]).astype(
+            jnp.float32)
+        return jnp.sum(correct.reshape(K, N) * mask) / jnp.maximum(
+            jnp.sum(mask), 1.0)
 
     return lm_evaluate
 
@@ -281,16 +292,33 @@ def lm_inactive_params(cfg) -> int:
         # (d_model x d_ff_expert) — mirrors models.lm.moe.init_moe.
         mats = 3 if cfg.mlp in ("swiglu", "geglu") else 2
         per_expert = mats * cfg.d_model * cfg.moe.d_ff_expert
-        idle = cfg.moe.n_experts - min(cfg.moe.top_k, cfg.moe.n_experts)
+        # Of the experts held here, a token fires top_k / n_experts.
+        held = len(cfg.moe.held_ids)
+        idle = held - min(cfg.moe.top_k, cfg.moe.n_experts) * held \
+            / cfg.moe.n_experts
         moe_layers = sum(s.n_layers for s in cfg.resolved_segments
                          if s.kind == "moe")
-        inactive += moe_layers * idle * per_expert
+        inactive += int(round(moe_layers * idle * per_expert))
     return inactive
 
 
+def routing_stats(aux: dict) -> dict:
+    """The held experts' routing counters of one step, from
+    `forward_train`'s `moe_picks` (MoE layers x held experts): the
+    token-picks that landed on them, the most and the mean on one expert
+    (each summed over layers), and the tokens dropped, none in the
+    dropless layer."""
+    picks = aux["moe_picks"]
+    return {"moe.local_picks": jnp.sum(picks),
+            "moe.local_picks_max": jnp.sum(jnp.max(picks, axis=-1)),
+            "moe.local_picks_mean": jnp.sum(
+                jnp.mean(picks.astype(jnp.float32), axis=-1)),
+            "moe.dropped": jnp.zeros((), picks.dtype)}
+
+
 def lm_workload(cfg, *, name: str | None = None, seq_len: int = 32,
-                samples_per_client: int = 32, eval_samples: int = 8
-                ) -> Workload:
+                samples_per_client: int = 32, eval_samples: int = 8,
+                with_routing_stats: bool = False) -> Workload:
     """Federate any `repro.models.lm` ModelConfig over token shards.
 
     The cost model is the standard transformer estimate: 6 FLOP per
@@ -306,7 +334,10 @@ def lm_workload(cfg, *, name: str | None = None, seq_len: int = 32,
 
     def loss_fn(params, xb, yb):
         del yb                     # targets are xb shifted by one token
-        return lm_loss(cfg, params, {"tokens": xb})[0]
+        loss, metrics = lm_loss(cfg, params, {"tokens": xb[:, :-1],
+                                              "targets": xb[:, 1:]})
+        return (loss, routing_stats(metrics)) if with_routing_stats \
+            else loss
 
     bytes_per_param = jnp.dtype(cfg.dtype).itemsize
     return Workload(
@@ -326,6 +357,7 @@ def lm_workload(cfg, *, name: str | None = None, seq_len: int = 32,
         inactive_params=lm_inactive_params(cfg),
         samples_per_epoch=samples_per_client,
         bytes_per_param=int(bytes_per_param),
+        loss_has_aux=with_routing_stats,
     )
 
 
@@ -370,6 +402,22 @@ def _lm_hybrid_tiny() -> Workload:
                        samples_per_client=32, eval_samples=8)
 
 
+def _moonlight_16b_a3b() -> Workload:
+    """Moonlight-16B-A3B at its published widths, cut to one chip's share
+    of an 8-chip expert-parallel deployment (`configs.moonlight_16b_a3b`:
+    the dense layer and 5 MoE layers, 8 of 64 experts, 20,480 of 163,840
+    ids; 669 M parameters), in float32, each layer rematerialised in the
+    backward pass, on rows of 4,096 tokens. Its loss returns the held
+    experts' routing counters."""
+    from repro.configs import get_config
+    from repro.configs.moonlight_16b_a3b import CHIP_SHARE
+    cfg = dataclasses.replace(
+        get_config("moonlight-16b-a3b").share(**CHIP_SHARE), remat=True)
+    return lm_workload(cfg, name="moonlight_16b_a3b", seq_len=4096,
+                       samples_per_client=6, eval_samples=1,
+                       with_routing_stats=True)
+
+
 # Registry entries are built lazily (constructing the LM workload touches
 # the model stack) and cached after first use.
 _BUILDERS: dict[str, Callable[[], Workload]] = {
@@ -379,6 +427,7 @@ _BUILDERS: dict[str, Callable[[], Workload]] = {
     "lm_moe_tiny": _lm_moe_tiny,
     "lm_rwkv6_tiny": _lm_rwkv6_tiny,
     "lm_hybrid_tiny": _lm_hybrid_tiny,
+    "moonlight_16b_a3b": _moonlight_16b_a3b,
 }
 _CACHE: dict[str, Workload] = {}
 

@@ -60,7 +60,9 @@ def attention_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
         o = jnp.einsum("bgrqk,bkgd->bqgrd", p, v)
         return None, o
 
-    _, outs = jax.lax.scan(body, None, (qg, qp))
+    # Each chunk's scores are recomputed in the backward pass, so a
+    # gradient also holds one chunk's (chunk x Sk) scores at a time.
+    _, outs = jax.lax.scan(jax.checkpoint(body), None, (qg, qp))
     o = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S + pad, H, Dv)
     return o[:, :S]
 

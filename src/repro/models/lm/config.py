@@ -22,18 +22,34 @@ from typing import Literal
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts. `scoring` "softmax" is the switch-style router
+    (balance loss plus z-loss); "sigmoid" is DeepSeek-V3's (noaux_tc):
+    sigmoid scores, a selection bias that only picks the experts, gates
+    normalised over the picks and scaled by `routed_scale`, and the
+    sequence-wise balance loss with `router_aux_coef` as its alpha.
+    `held` names the experts this chip holds under expert parallelism
+    (None: all of them); the router still scores all `n_experts`."""
     n_experts: int
     top_k: int
     d_ff_expert: int
     n_shared: int = 0           # shared (always-on) experts, DeepSeek-style
-    capacity_factor: float = 2.0
+    capacity_factor: float = 2.0  # the all-to-all path's buffers only
     router_aux_coef: float = 0.01
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scale: float = 1.0
+    held: tuple[int, ...] | None = None
+
+    @property
+    def held_ids(self) -> tuple[int, ...]:
+        return tuple(range(self.n_experts)) if self.held is None \
+            else tuple(self.held)
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V3 multi-head latent attention."""
-    q_lora_rank: int = 1536
+    """DeepSeek-V3 multi-head latent attention. `q_lora_rank` None makes
+    the query one direct projection (DeepSeek-V2-Lite, Moonlight)."""
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     rope_head_dim: int = 64
     nope_head_dim: int = 128
@@ -141,6 +157,37 @@ class ModelConfig:
                        for s in self.resolved_segments)
         return has_state or (any_attn and windowed)
 
+    def share(self, moe_layers: int, held: tuple[int, ...],
+              vocab_size: int) -> "ModelConfig":
+        """One chip's share of a deployment that divides each layer over
+        chips by experts (the `model-configs` guide, section 4): the
+        leading dense layers, the first `moe_layers` MoE layers (the rest
+        would be further pipeline stages), the routed experts `held` of
+        each MoE layer, and the first `vocab_size` ids of the vocabulary
+        for the embedding and the head. Every width stays as published,
+        and so do the router's outputs and its experts per token."""
+        segs, seen = [], 0
+        for s in self.resolved_segments:
+            if s.kind != "moe":
+                if seen:
+                    break
+                segs.append(s)
+                continue
+            take = min(s.n_layers, moe_layers - seen)
+            if take <= 0:
+                break
+            segs.append(dataclasses.replace(s, n_layers=take))
+            seen += take
+        if seen != moe_layers or any(not 0 <= e < self.moe.n_experts
+                                     for e in held):
+            raise ValueError(f"{self.name}: no share of {moe_layers} MoE "
+                             f"layers holding experts {held}")
+        return dataclasses.replace(
+            self, name=f"{self.name}-share", segments=tuple(segs),
+            n_layers=sum(s.n_layers for s in segs),
+            vocab_size=int(vocab_size),
+            moe=dataclasses.replace(self.moe, held=tuple(held)))
+
     def reduced(self, n_layers: int = 2, d_model: int = 256,
                 n_experts: int = 4) -> "ModelConfig":
         """Smoke-test variant: same family, tiny dimensions."""
@@ -164,10 +211,11 @@ class ModelConfig:
                 self.moe, n_experts=min(n_experts, self.moe.n_experts),
                 top_k=min(self.moe.top_k, 2),
                 d_ff_expert=d_model, n_shared=min(self.moe.n_shared, 1),
-                capacity_factor=8.0)   # effectively dropless at smoke scale
+                capacity_factor=8.0, held=None)
         mla = None
         if self.mla is not None:
-            mla = MLAConfig(q_lora_rank=64, kv_lora_rank=32,
+            mla = MLAConfig(q_lora_rank=None if self.mla.q_lora_rank is None
+                            else 64, kv_lora_rank=32,
                             rope_head_dim=32, nope_head_dim=hd, v_head_dim=hd)
         ssm = None
         if self.ssm is not None:

@@ -20,10 +20,14 @@ from repro.models.lm.layers import apply_rope, dense_init, rmsnorm
 def init_mla(rng, d_model: int, n_heads: int, cfg: MLAConfig) -> dict:
     ks = jax.random.split(rng, 8)
     qh = cfg.nope_head_dim + cfg.rope_head_dim
+    if cfg.q_lora_rank is None:
+        q = {"wq": dense_init(ks[0], (d_model, n_heads * qh))}
+    else:
+        q = {"wq_a": dense_init(ks[0], (d_model, cfg.q_lora_rank)),
+             "q_norm": jnp.zeros((cfg.q_lora_rank,)),
+             "wq_b": dense_init(ks[1], (cfg.q_lora_rank, n_heads * qh))}
     return {
-        "wq_a": dense_init(ks[0], (d_model, cfg.q_lora_rank)),
-        "q_norm": jnp.zeros((cfg.q_lora_rank,)),
-        "wq_b": dense_init(ks[1], (cfg.q_lora_rank, n_heads * qh)),
+        **q,
         "wkv_a": dense_init(
             ks[2], (d_model, cfg.kv_lora_rank + cfg.rope_head_dim)),
         "kv_norm": jnp.zeros((cfg.kv_lora_rank,)),
@@ -37,8 +41,11 @@ def init_mla(rng, d_model: int, n_heads: int, cfg: MLAConfig) -> dict:
 
 def _project_q(p, x, n_heads, cfg: MLAConfig, positions, theta):
     B, S, _ = x.shape
-    cq = rmsnorm(x @ p["wq_a"], p["q_norm"])
-    q = (cq @ p["wq_b"]).reshape(
+    if "wq" in p:                           # q_lora_rank None: direct
+        q = x @ p["wq"]
+    else:
+        q = rmsnorm(x @ p["wq_a"], p["q_norm"]) @ p["wq_b"]
+    q = q.reshape(
         B, S, n_heads, cfg.nope_head_dim + cfg.rope_head_dim)
     q_nope, q_rope = jnp.split(q, [cfg.nope_head_dim], axis=-1)
     q_rope = apply_rope(q_rope, positions, theta)

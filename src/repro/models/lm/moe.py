@@ -1,19 +1,25 @@
-"""Top-k routed mixture-of-experts with sort-based capacity dispatch.
+"""Top-k routed mixture-of-experts.
 
-The dispatch is GShard-style but without the (T, E, C) one-hot tensor:
-token->expert assignments are sorted, positions within each expert group
-are computed from cumulative counts, and tokens scatter into an
-(E, C, d_model) buffer that feeds *batched* per-expert matmuls
-(einsum over the expert axis — MXU-friendly, shards cleanly: E over the
-fsdp axes, expert d_ff over the model axis). Overflowing tokens are
-dropped (capacity_factor controls slack), underfull slots are zero.
+`apply_moe` is dropless: the router scores all `n_experts`, and the
+token-picks that land on the experts this chip holds (`MoEConfig.held`)
+are sorted by expert and run through a grouped matmul
+(`jax.lax.ragged_dot`) sized by the picks each expert got, so no token
+is dropped however uneven the routing. What the absent experts would add
+is left out, as expert parallelism leaves it to the chips that hold them;
+the shared experts are added once, on every chip.
 
-Aux outputs: switch-style load-balance loss + router z-loss.
+`apply_moe_ep` (the production-mesh all-to-all) keeps GShard-style
+capacity buffers (`capacity_factor`): sort-based dispatch into an
+(E, C, d_model) buffer, with overflowing tokens dropped.
+
+Aux outputs: the balance loss of the router's kind, the softmax router's
+z-loss, and `picks`, the token-picks each held expert got.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.lm.config import MoEConfig
 from repro.models.lm.layers import apply_mlp, dense_init, init_mlp
@@ -21,12 +27,15 @@ from repro.models.lm.layers import apply_mlp, dense_init, init_mlp
 
 def init_moe(rng, d_model: int, cfg: MoEConfig, mlp_kind: str) -> dict:
     ks = jax.random.split(rng, 8)
-    e, ff = cfg.n_experts, cfg.d_ff_expert
+    e, ff = len(cfg.held_ids), cfg.d_ff_expert
     p = {
-        "router": dense_init(ks[0], (d_model, e), scale=d_model ** -0.5),
+        "router": dense_init(ks[0], (d_model, cfg.n_experts),
+                             scale=d_model ** -0.5),
         "w1": dense_init(ks[1], (e, d_model, ff)),
         "w2": dense_init(ks[2], (e, ff, d_model)),
     }
+    if cfg.scoring == "sigmoid":
+        p["router_bias"] = jnp.zeros((cfg.n_experts,))
     if mlp_kind in ("swiglu", "geglu"):
         p["w3"] = dense_init(ks[3], (e, d_model, ff))
     if cfg.n_shared:
@@ -48,11 +57,32 @@ def _expert_ffn(p: dict, x: jax.Array, kind: str) -> jax.Array:
     return jnp.einsum("ecf,efd->ecd", h, p["w2"])
 
 
-def _route(p: dict, xf: jax.Array, cfg: MoEConfig):
-    """Router + aux losses. xf: (T, d)."""
+def _route(p: dict, xf: jax.Array, cfg: MoEConfig, rows: int = 1):
+    """Router + aux losses. xf: (T, d), `rows` sequences of T // rows.
+
+    The router runs in float32 at full matmul precision, as the published
+    models do: near-ties among the top-k are common, and one that breaks
+    the other way sends a token to another expert."""
     E, K = cfg.n_experts, cfg.top_k
     T = xf.shape[0]
-    logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
+    logits = jnp.dot(xf.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)     # (T, E)
+    if cfg.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        # The bias picks the experts and weighs nothing (noaux_tc).
+        _, expert_ids = jax.lax.top_k(scores + p["router_bias"], K)
+        gate_vals = jnp.take_along_axis(scores, expert_ids, axis=-1)
+        gate_vals = cfg.routed_scale * gate_vals / (
+            gate_vals.sum(-1, keepdims=True) + 1e-20)
+        # Sequence-wise balance loss (DeepSeek-V3, eqs. 17-20): per
+        # sequence, f_i = E/(K S) * picks of i, P_i = mean normalised score.
+        hits = jax.nn.one_hot(expert_ids, E).sum(1).reshape(rows, -1, E)
+        share = (scores / scores.sum(-1, keepdims=True)).reshape(rows, -1, E)
+        f = hits.mean(1) * (E / K)
+        aux = {"load_balance": cfg.router_aux_coef * jnp.mean(
+                   jnp.sum(f * share.mean(1), axis=-1)),
+               "router_z": jnp.zeros((), jnp.float32)}
+        return gate_vals, expert_ids, aux
     probs = jax.nn.softmax(logits, axis=-1)                   # (T, E)
     gate_vals, expert_ids = jax.lax.top_k(probs, K)           # (T, K)
     gate_vals = gate_vals / jnp.maximum(
@@ -94,47 +124,124 @@ def _combine_tokens(y_slots, meta, T: int, dtype):
     return jnp.zeros((T, y_slots.shape[-1]), dtype).at[st].add(contrib)
 
 
-# Rows shorter than this use one global dispatch (decode: S == 1).
-_ROW_DISPATCH_MIN_S = 64
+@jax.custom_vjp
+def _dispatch(xf, tok, inv, n_live):
+    """xf[tok]: the held picks' token rows, in expert order. `inv` (S, K)
+    gives each pick's place in that order (`n_live` or more: not held),
+    so the backward pass sums each token's rows by gathers, with no
+    scatter."""
+    return xf[tok]
+
+
+def _dispatch_fwd(xf, tok, inv, n_live):
+    return xf[tok], (inv, n_live)
+
+
+def _dispatch_bwd(res, g):
+    inv, n_live = res
+    # The grouped matmul leaves the rows past its groups unwritten:
+    # select them away (where, not a product), and add a zero row for
+    # the picks not held.
+    live = (jnp.arange(g.shape[0]) < n_live)[:, None]
+    gz = jnp.concatenate([jnp.where(live, g, 0.0), jnp.zeros_like(g[:1])])
+    idx = jnp.minimum(inv, g.shape[0])
+    dx = sum(gz[idx[:, k]] for k in range(inv.shape[1]))
+    return dx, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, gates, tok, order, inv):
+    """y[t] = sum_k gates[t, k] * ys[inv[t, k]], a pick not held (`inv`
+    past the last row) adding nothing. Forward and backward are gathers,
+    token by token or row by row."""
+    return _combine_fwd(ys, gates, tok, order, inv)[0]
+
+
+def _combine_fwd(ys, gates, tok, order, inv):
+    yz = jnp.concatenate([ys, jnp.zeros_like(ys[:1])])
+    idx = jnp.minimum(inv, ys.shape[0])
+    y = sum(gates[:, k, None].astype(ys.dtype) * yz[idx[:, k]]
+            for k in range(inv.shape[1]))
+    return y, (yz, gates, tok, order, inv)
+
+
+def _combine_bwd(res, g):
+    yz, gates, tok, order, inv = res
+    m = yz.shape[0] - 1
+    idx = jnp.minimum(inv, m)
+    dgates = jnp.stack([jnp.sum(g * yz[idx[:, k]], axis=-1)
+                        for k in range(inv.shape[1])], axis=1)
+    g_sorted = gates.reshape(-1)[order[:m]].astype(g.dtype)
+    return (g[tok] * g_sorted[:, None], dgates.astype(gates.dtype), None,
+            None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def held_experts(p: dict, xf: jax.Array, gate_vals, expert_ids,
+                 cfg: MoEConfig, kind: str):
+    """The held experts' part of one sequence's layer output, dropless.
+    xf: (S, d); gate_vals, expert_ids: (S, K).
+
+    Each of the S*K token-picks is mapped to a held-expert slot (H for an
+    expert held elsewhere) and the picks are sorted by slot, so the held
+    ones come first, grouped by expert. A token picks an expert at most
+    once, so at most m = S * min(K, H) picks are held: the grouped matmul
+    gets m rows and computes only the rows its groups cover. Returns
+    (y (S, d), picks (H,) int32)."""
+    S, d = xf.shape
+    K = expert_ids.shape[-1]
+    held = cfg.held_ids
+    H, m = len(held), S * min(K, len(held))
+    slot_of = np.full((cfg.n_experts,), H, np.int32)
+    slot_of[list(held)] = np.arange(H)
+    slots = jnp.asarray(slot_of)[expert_ids].reshape(-1)      # (S*K,)
+    picks = jnp.sum(jax.nn.one_hot(slots, H, dtype=jnp.int32), axis=0)
+    order = jnp.argsort(slots)
+    inv = jnp.argsort(order).reshape(S, K).astype(jnp.int32)
+    tok = order[:m] // K
+    n_live = jnp.sum(picks)
+    live = (jnp.arange(m) < n_live)[:, None]
+
+    def grouped(a, w):
+        # The rows past the groups are left unwritten: select them away,
+        # so that no elementwise step or gradient reads them.
+        return jnp.where(live, jax.lax.ragged_dot(a, w, picks), 0.0)
+
+    xs = _dispatch(xf, tok, inv, n_live)
+    h = grouped(xs, p["w1"])
+    if kind == "swiglu":
+        h = jax.nn.silu(h) * grouped(xs, p["w3"])
+    elif kind == "geglu":
+        h = jax.nn.gelu(h, approximate=True) * grouped(xs, p["w3"])
+    else:
+        h = jax.nn.gelu(h, approximate=True)
+    y = _combine(grouped(h, p["w2"]), gate_vals, tok, order, inv)
+    return y.astype(xf.dtype), picks
 
 
 def apply_moe(p: dict, x: jax.Array, cfg: MoEConfig, mlp_kind: str
               ) -> tuple[jax.Array, dict]:
-    """x: (B, S, d) -> (y, aux). Routed top-k + optional shared experts.
-
-    Dispatch is *batch-row-local* for full sequences: each row sorts and
-    capacity-buffers its own S*K assignments under vmap, so the token axis
-    keeps its data-parallel sharding end to end — a global argsort over
-    B*S tokens would force GSPMD to all-gather the whole token buffer
-    (measured: the difference between a collective-bound 2000s step and a
-    compute-bound one on deepseek-v3 / 256 chips; EXPERIMENTS.md §Perf).
-    Capacity is enforced per row (C = ceil(S*K*cf/E)), which is also the
-    per-device semantics real EP systems implement. Decode (S == 1) keeps
-    the single global dispatch.
-    """
+    """x: (B, S, d) -> (y, aux). Routed top-k over all experts, the held
+    experts' part computed with no token dropped, one sequence at a time
+    (its activations recomputed in the backward pass), plus the shared
+    experts. aux: `load_balance`, `router_z` and `picks` (H,)."""
     B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
     xf = x.reshape(B * S, d)
-    gate_vals, expert_ids, aux = _route(p, xf, cfg)
+    gate_vals, expert_ids, aux = _route(p, xf, cfg, rows=B)
 
-    if S >= _ROW_DISPATCH_MIN_S:
-        C = int(max(1, round(S * K * cfg.capacity_factor / E)))
+    @jax.checkpoint
+    def row(args):
+        return held_experts(p, *args, cfg, mlp_kind)
 
-        def per_row(xr, gr, er):
-            buf, meta = _dispatch_tokens(xr, gr, er, E, C)
-            h = _expert_ffn(p, buf, mlp_kind)
-            return _combine_tokens(h.reshape(E * C, d), meta, S, x.dtype)
-
-        y = jax.vmap(per_row)(x, gate_vals.reshape(B, S, K),
-                              expert_ids.reshape(B, S, K))
-        y = y.reshape(B * S, d)
-    else:
-        T = B * S
-        C = int(max(1, round(T * K * cfg.capacity_factor / E)))
-        buf, meta = _dispatch_tokens(xf, gate_vals, expert_ids, E, C)
-        h = _expert_ffn(p, buf, mlp_kind)
-        y = _combine_tokens(h.reshape(E * C, d), meta, T, x.dtype)
-
+    y, picks = jax.lax.map(row, (x, gate_vals.reshape(B, S, -1),
+                                 expert_ids.reshape(B, S, -1)))
+    y = y.reshape(B * S, d)
+    aux["picks"] = jnp.sum(picks, axis=0)
     if cfg.n_shared:
         y = y + apply_mlp(p["shared"], xf, mlp_kind)
     return y.reshape(B, S, d), aux

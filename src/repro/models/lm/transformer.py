@@ -223,7 +223,7 @@ def _apply_layer_train(cfg: ModelConfig, seg: Segment, lp: dict,
         x = x + o
         o, _ = rwkv_channel_mix(lp["cm"],
                                 rmsnorm(x, lp["norm2"], cfg.norm_eps))
-        return x + o, aux
+        return x + o, aux, None
 
     h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
     if "mla" in lp:
@@ -242,20 +242,23 @@ def _apply_layer_train(cfg: ModelConfig, seg: Segment, lp: dict,
                          kv_override=cross_kv(lp["xattn"], enc_out, cfg))
         x = x + o
     h2 = rmsnorm(x, lp["norm2"], cfg.norm_eps)
+    picks = None
     if seg.kind == "moe":
         o, moe_aux = _moe_block(lp["moe"], h2, cfg)
         aux = aux + moe_aux["load_balance"] + moe_aux["router_z"]
+        picks = moe_aux.get("picks")
     else:
         o = apply_mlp(lp["mlp"], h2, cfg.mlp)
-    return x + o, aux
+    return x + o, aux, picks
 
 
 def _moe_block(p, h, cfg: ModelConfig):
     """Routed experts: expert-parallel all-to-all when the launcher has
-    declared an EP axis and the expert count divides it, else the
-    row-local dispatch."""
+    declared an EP axis and the expert count divides it (softmax routers
+    holding every expert), else the dropless held-expert layer."""
     ep = ep_axis()
-    if ep is not None:
+    if (ep is not None and cfg.moe.scoring == "softmax"
+            and cfg.moe.held is None):
         dp_axes, name, size, mesh = ep
         if cfg.moe.n_experts % size == 0 and h.shape[1] > 1:
             return apply_moe_ep(p, h, cfg.moe, cfg.mlp, dp_axes, name, size,
@@ -432,7 +435,9 @@ def _logits(cfg: ModelConfig, params, x):
 
 def _scan_segments(cfg: ModelConfig, params, x, positions, mode: str,
                    caches=None, pos=None, max_seq=None, enc_out=None):
-    """Run every segment with lax.scan over its stacked layers."""
+    """Run every segment with lax.scan over its stacked layers. Returns
+    (x, aux, per segment: its caches, or in training each MoE segment's
+    token-picks per layer and held expert)."""
     # zeros_like inherits x's varying mesh axes, so the scan carry keeps
     # one type when this runs inside a shard_map body.
     aux_total = jnp.zeros_like(x, shape=(), dtype=jnp.float32)
@@ -442,14 +447,16 @@ def _scan_segments(cfg: ModelConfig, params, x, positions, mode: str,
         if mode == "train":
             def body(carry, lp, seg=seg):
                 h, aux = carry
-                h, a = _apply_layer_train(cfg, seg, lp, h, positions,
-                                          enc_out=enc_out)
-                return (h, aux + a), None
+                h, a, picks = _apply_layer_train(cfg, seg, lp, h, positions,
+                                                 enc_out=enc_out)
+                return (h, aux + a), picks
             if cfg.remat:
                 body = jax.checkpoint(body,
                                       policy=jax.checkpoint_policies.nothing_saveable)
-            (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), sp,
-                                             unroll=cfg.scan_unroll)
+            (x, aux_total), picks = jax.lax.scan(body, (x, aux_total), sp,
+                                                 unroll=cfg.scan_unroll)
+            if picks is not None:
+                new_caches.append(picks)
         elif mode == "prefill":
             def body(carry, lp, seg=seg):
                 h, aux = carry
@@ -494,15 +501,18 @@ def encoder_forward(cfg: ModelConfig, params, enc_embeds):
 
 def forward_train(cfg: ModelConfig, params, tokens, prefix_embeds=None,
                   enc_embeds=None):
-    """Full-sequence forward. Returns (logits (B,S,V), aux dict)."""
+    """Full-sequence forward. Returns (logits (B,S,V), aux dict): the
+    summed router losses `moe_aux` and, with MoE layers, `moe_picks`."""
     enc_out = None
     if cfg.encoder is not None:
         assert enc_embeds is not None, "enc-dec model needs encoder embeds"
         enc_out = encoder_forward(cfg, params, enc_embeds)
     x, positions = _embed(cfg, params, tokens, prefix_embeds)
-    x, aux, _ = _scan_segments(cfg, params, x, positions, "train",
-                               enc_out=enc_out)
+    x, aux, picks = _scan_segments(cfg, params, x, positions, "train",
+                                   enc_out=enc_out)
     out = {"moe_aux": aux}
+    if picks:           # (MoE layers, held experts) token-picks
+        out["moe_picks"] = jnp.concatenate(picks)
     if cfg.mtp and "mtp_head" in params:
         h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
         out["mtp_logits"] = h @ params["mtp_head"]

@@ -21,10 +21,14 @@ def client_mesh(n_clients: int, *, axis: str = "pod", devices=None):
     Uses min(n_devices, n_clients) devices; callers pad their client batch
     to a multiple of the axis size (`pad_client_count`) with zero-weight
     slots, the collective equivalent of an out-of-contact satellite.
+    The axis is `Auto`: outside the shard_map body the model it returns
+    is an ordinary replicated array, which any later program (evaluation,
+    a primitive with no explicit-sharding rule such as `ragged_dot`) takes.
     """
     devices = list(devices if devices is not None else jax.devices())
     n = max(1, min(len(devices), int(n_clients)))
-    return jax.make_mesh((n,), (axis,), devices=devices[:n])
+    return jax.make_mesh((n,), (axis,), devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def pad_client_count(n_clients: int, mesh, axis: str = "pod") -> int:

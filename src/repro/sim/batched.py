@@ -324,6 +324,12 @@ class BatchedSweep:
                 raise ValueError("record_params is unsupported under "
                                  "BatchedSweep (parity harness: use the "
                                  "loop path)")
+            if self.train and sim.workload.loss_has_aux:
+                raise ValueError(
+                    f"scenario {name!r}: workload {sim.workload.name!r} "
+                    "returns per-step statistics with its loss; the "
+                    "batched sweep carries parameters only — run it "
+                    "through the loop path")
             if sim.execution == "mesh":
                 raise ValueError(
                     f"scenario {name!r} requests mesh execution; the "

@@ -35,7 +35,10 @@ accuracy, round duration, and per-satellite idle time.
 overridable per run with `ConstellationSim(..., execution=...)`):
 
   * "host" — the reference path: one jitted vmap over stacked clients,
-    then `Strategy.aggregate` as a host-side weighted reduction;
+    then `Strategy.aggregate` as a host-side weighted reduction; where
+    the round's model copies do not fit the device together
+    (`copies_fit`), one program trains its clients one after another and
+    folds each return into one donated accumulator (`_stream_clients`);
   * "mesh" — cluster-as-collective (`launch.fl_round.make_mesh_round_step`):
     each participating satellite is a pod slot on a mesh axis, local SGD
     runs inside shard_map, and aggregation is a participation-masked psum.
@@ -47,6 +50,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import heapq
 from typing import Iterable
 
@@ -61,10 +65,18 @@ from repro.comms.contact_plan import (
 )
 from repro.comms.isl import ISLTopology, compute_isl_windows
 from repro.comms.links import ConstantRate, LinkModel
-from repro.core.aggregation import admission_weights
-from repro.core.client import vmapped_client_update
+from repro.core.aggregation import (
+    admission_weights,
+    fold_weighted,
+    normalized_weights,
+)
+from repro.core.client import (
+    make_client_update,
+    vmapped_client_update,
+    zero_stats,
+)
 from repro.core.spaceify import SpaceifiedAlgorithm
-from repro.core.strategies.base import BufferState, PendingUpdate
+from repro.core.strategies.base import BufferState, PendingUpdate, Strategy
 from repro.core.timing import HardwareModel
 from repro.core.workload import Workload, get_workload, validate_execution
 from repro.data.federated import FederatedDataset
@@ -109,6 +121,9 @@ def client_steps(n_k: int, epochs: int, batch_size: int,
 
 TRAFFIC_COUNTERS = ("sim.h2d_bytes", "sim.d2h_bytes", "sim.host_syncs",
                     "sim.gathered_rows")
+# Counted from what the client program returns (`count_returned`).
+ROUTING_COUNTERS = ("moe.local_picks", "moe.local_picks_max",
+                    "moe.local_picks_mean", "moe.dropped")
 
 
 def to_device(x, dtype=None) -> jax.Array:
@@ -177,23 +192,54 @@ def to_host(tree):
     return out
 
 
+def count_returned(stats) -> None:
+    """Add the counters a client program returned (`Workload.loss_has_aux`
+    stats, summed over its clients) to the tracer. Only under a syncing
+    tracer, which waits on the device anyway: untraced runs read nothing
+    back for them."""
+    if stats and syncing():
+        for name, v in to_host(stats).items():
+            count(name, float(np.sum(v)))
+
+
+@functools.cache
+def device_bytes_limit() -> int | None:
+    """The first device's memory limit; None where the backend reports
+    none (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def copies_fit(params, n_clients: int, limit: int | None) -> bool:
+    """Whether a round's `n_clients` stacked model copies fit a device of
+    `limit` bytes. A stacked client holds about three copies of the model
+    (its start, its carried state, its gradient); the global model and
+    the result are two more, and half of the device is left for
+    activations. No limit (the CPU) fits every round."""
+    if not limit:
+        return True
+    model = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    return (2 + 3 * n_clients) * model <= limit / 2
+
+
 @contextlib.contextmanager
 def run_span(**args):
     """The `sim.run` span around one whole run (`ConstellationSim.run`,
     `BatchedSweep.run`). On a clean exit it carries the run's host-device
     traffic as args `h2d_bytes`, `d2h_bytes`, `host_syncs` and
-    `gathered_rows`: how much each of `TRAFFIC_COUNTERS` grew during the
-    run (counters are global to the tracer; the args price one run from
-    its span alone)."""
+    `gathered_rows`, and its experts' routing as `local_picks`,
+    `local_picks_max`, `local_picks_mean` and `dropped`: how much each of `TRAFFIC_COUNTERS`
+    and `ROUTING_COUNTERS` grew during the run (counters are global to
+    the tracer; the args price one run from its span alone)."""
     tracer = get_tracer()
+    names = TRAFFIC_COUNTERS + ROUTING_COUNTERS
     with span("sim.run", **args) as sp:
         if tracer is None:
             yield
             return
-        before = [tracer.counter(c) for c in TRAFFIC_COUNTERS]
+        before = [tracer.counter(c) for c in names]
         yield
         sp.set(**{c.split(".", 1)[1]: tracer.counter(c) - b
-                  for c, b in zip(TRAFFIC_COUNTERS, before)})
+                  for c, b in zip(names, before)})
 
 
 def traced_jit_call(sp, jitted, *args):
@@ -379,10 +425,14 @@ class ConstellationSim:
                     "engine's mesh path carries a single (x, y) sample "
                     "stream per pod slot — run with execution='host' or "
                     "drive launch.fl_round.make_fl_round_step directly")
+            if self.workload.loss_has_aux:
+                raise ValueError(
+                    f"workload {self.workload.name!r} returns per-step "
+                    "statistics with its loss; the mesh round step "
+                    "carries parameters only — run with execution='host'")
             # The collective realizes exactly the weighted-average /
             # discounted-delta family; a custom Strategy.aggregate would
             # be silently bypassed, so refuse instead.
-            from repro.core.strategies.base import Strategy
             from repro.core.strategies.fedbuff import FedBuffSat
             agg = type(algorithm.strategy).aggregate
             if agg not in (Strategy.aggregate, FedBuffSat.aggregate):
@@ -400,7 +450,7 @@ class ConstellationSim:
             assert self.data.n_clients == constellation.n_sats
             # Jitted updaters are built lazily per power-of-two step bound so
             # a 45-step FedAvg round never pays for the 128-step worst case.
-            self._updaters: dict[tuple[int, bool], object] = {}
+            self._updaters: dict[tuple, object] = {}
             # Mesh-path caches: one client mesh per pod-axis size, one
             # jitted collective round step per (step bound, axis size).
             self._meshes: dict[int, object] = {}
@@ -412,7 +462,61 @@ class ConstellationSim:
             self._updaters[key] = jax.jit(vmapped_client_update(
                 self.workload.loss_fn, lr=self.cfg.lr,
                 batch_size=self.cfg.batch_size, max_steps=bound,
-                anchored=anchored))
+                anchored=anchored, has_aux=self.workload.loss_has_aux))
+        return self._updaters[key]
+
+    def _start(self, params, x, y, stacked=None):
+        """The vmapped update's start state for the clients with shards
+        `x`, `y`: `stacked` (their own start models) or else `params`
+        broadcast to each, with zero stats beside it where the workload's
+        loss returns stats."""
+        k = x.shape[0]
+        if stacked is None:
+            stacked = jax.tree.map(
+                lambda a: jnp.broadcast_to(a, (k,) + a.shape), params)
+        if not self.workload.loss_has_aux:
+            return stacked
+        zeros = zero_stats(self.workload.loss_fn, params, x[0], y[0],
+                           self.cfg.batch_size)
+        return stacked, jax.tree.map(
+            lambda z: jnp.broadcast_to(z, (k,) + z.shape), zeros)
+
+    def _folder(self, bound: int):
+        """Jitted: a round's clients, each trained from `params` as the
+        vmapped update trains it, one after another (the TPU's grouped
+        matmul takes no batch axis), each weighted return added to the
+        accumulator as it lands; the accumulator is donated. The local
+        steps are unrolled, so the parameters are updated in place.
+        (acc, params, x, y, n, steps, w, prox_mu, rngs) -> (acc, the
+        clients' stats summed)."""
+        key = (bound, "fold")
+        if key not in self._updaters:
+            wl, cfg = self.workload, self.cfg
+            update = make_client_update(
+                loss_fn=wl.loss_fn, lr=cfg.lr, batch_size=cfg.batch_size,
+                max_steps=bound, has_aux=wl.loss_has_aux, unroll=True)
+
+            def fold(acc, params, x, y, n, steps, w, prox_mu, rngs):
+                stats = {}
+                if wl.loss_has_aux:
+                    stats = zero_stats(wl.loss_fn, params, x[0], y[0],
+                                       cfg.batch_size)
+
+                def client(carry, row):
+                    acc, stats = carry
+                    xk, yk, nk, sk, wk, rk = row
+                    start = (params, stats) if wl.loss_has_aux else params
+                    out = update(start, params, xk, yk, nk, sk, prox_mu, rk)
+                    out, stats = out if wl.loss_has_aux else (out, stats)
+                    acc = fold_weighted(
+                        acc, jax.tree.map(lambda a: a[None], out), wk[None])
+                    return (acc, stats), None
+
+                (acc, stats), _ = jax.lax.scan(
+                    client, (acc, stats), (x, y, n, steps, w, rngs))
+                return acc, stats
+
+            self._updaters[key] = jax.jit(fold, donate_argnums=0)
         return self._updaters[key]
 
     def _client_mesh(self, n_clients: int):
@@ -461,6 +565,16 @@ class ConstellationSim:
     # ------------------------------------------------------------------ #
     # Shared round-execution core (sync barrier AND async buffer flushes)
     # ------------------------------------------------------------------ #
+    def _round_inputs(self, ks: list[int], epochs: list[int], rng):
+        """The round's local-step counts (uploaded), one key a client and
+        the power-of-two step bound. The run's training shards go up to
+        the device at its first trained round (`resident_shards`)."""
+        steps_np = [self._steps_for(k, e) for k, e in zip(ks, epochs)]
+        if self._shards is None:      # the run's first trained round
+            self._shards = resident_shards(self.data)
+        return (to_device(steps_np, jnp.int32),
+                jax.random.split(rng, len(ks)), self._bound(steps_np))
+
     def _run_clients(self, global_params, ks: list[int], epochs: list[int],
                      rng, anchors=None):
         """Train-batch assembly + vmapped ClientUpdate for `ks`.
@@ -474,26 +588,54 @@ class ConstellationSim:
         per-client anchor versions (FedBuff). Returns the stacked client
         parameter returns.
         """
-        steps_np = [self._steps_for(k, e) for k, e in zip(ks, epochs)]
-        steps = to_device(steps_np, jnp.int32)
-        if self._shards is None:      # the run's first trained round
-            self._shards = resident_shards(self.data)
+        steps, rngs, bound = self._round_inputs(ks, epochs, rng)
         x, y, n = self._shards(ks)
         anchored = anchors is not None
-        if anchored:
-            params0 = anchors
-        else:
+        params0 = self._start(global_params, x, y, stacked=anchors)
+        if not anchored:
             anchors = global_params
-            params0 = jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (len(ks),) + a.shape),
-                global_params)
-        rngs = jax.random.split(rng, len(ks))
-        bound = self._bound(steps_np)
         update = self._updater(bound, anchored=anchored)
         with span("sim.client_train", clients=len(ks),
                   step_bound=bound) as sp:
-            return traced_jit_call(sp, update, params0, anchors, x, y, n,
-                                   steps, self.alg.strategy.prox_mu, rngs)
+            out = traced_jit_call(sp, update, params0, anchors, x, y, n,
+                                  steps, self.alg.strategy.prox_mu, rngs)
+        if self.workload.loss_has_aux:
+            out, stats = out
+            count_returned(stats)
+        return out
+
+    def _stream_clients(self, global_params, ks: list[int],
+                        epochs: list[int], rng, weights):
+        """A synchronous round whose clients' model copies do not fit the
+        device together: one program trains its clients one after
+        another, each as `_run_clients` would train it within the whole
+        round (the same rows, keys and power-of-two step bound), and
+        folds each weighted return into one donated accumulator as it
+        lands. The accumulator is then the round's weighted average
+        (Eq. 1), `Strategy.aggregate`'s result, so the aggregation runs
+        inside the span `sim.client` that times the program (`clients`,
+        and `steps`, the local steps it executes)."""
+        strategy = self.alg.strategy
+        if self.codec.lossy or type(strategy).aggregate is not \
+                Strategy.aggregate:
+            raise ValueError(
+                f"{len(ks)} copies of workload {self.workload.name!r} do "
+                "not fit the device together; such rounds are folded one "
+                "client at a time for the synchronous weighted average "
+                f"alone, not for strategy {strategy.name!r}"
+                + (f" with codec {self.codec.name!r}" if self.codec.lossy
+                   else ""))
+        steps, rngs, bound = self._round_inputs(ks, epochs, rng)
+        x, y, n = self._shards(ks)
+        acc = jax.tree.map(jnp.zeros_like, global_params)
+        with span("sim.client_train", clients=len(ks), step_bound=bound), \
+                span("sim.client", clients=len(ks),
+                     steps=bound * len(ks)) as sp:
+            acc, stats = traced_jit_call(
+                sp, self._folder(bound), acc, global_params, x, y, n, steps,
+                normalized_weights(weights), strategy.prox_mu, rngs)
+        count_returned(stats)
+        return acc
 
     def _run_clients_mesh(self, global_params, ks: list[int],
                           epochs: list[int], rng, *, weights, staleness,
@@ -564,6 +706,14 @@ class ConstellationSim:
             return self._run_clients_mesh(
                 global_params, ks, epochs, rng, weights=weights,
                 staleness=staleness, anchors=anchors)
+        if not copies_fit(global_params, len(ks), device_bytes_limit()):
+            if anchors is not None:
+                raise ValueError(
+                    f"{len(ks)} copies of workload {self.workload.name!r} "
+                    "do not fit the device together; buffered rounds "
+                    "anchored on older versions are not streamed")
+            return self._stream_clients(global_params, ks, epochs, rng,
+                                        weights)
         stacked = self._run_clients(global_params, ks, epochs, rng,
                                     anchors=anchors)
         if self.codec.lossy:

@@ -44,24 +44,32 @@ def _ce(logits: jax.Array, labels: jax.Array) -> jax.Array:
 def lm_loss(cfg: ModelConfig, params, batch: Batch):
     """Next-token CE (+ MoE aux, + MTP head loss when configured).
 
-    batch: {"tokens": (B, S) int32, optional "prefix_embeds" (B, P, d),
-    optional "enc_embeds" (B, F, d)}. Prefix positions carry no loss.
+    batch: {"tokens": (B, S) int32, optional "targets" (B, S), the id
+    after each token (without them each token's target is the next
+    token, and the last one has none), optional "prefix_embeds"
+    (B, P, d), optional "enc_embeds" (B, F, d)}. Prefix positions carry
+    no loss.
     """
     tokens = batch["tokens"]
+    targets = batch.get("targets")
     logits, aux = forward_train(
         cfg, params, tokens,
         prefix_embeds=batch.get("prefix_embeds"),
         enc_embeds=batch.get("enc_embeds"))
     P = logits.shape[1] - tokens.shape[1]          # prefix length
     text_logits = logits[:, P:, :]
-    loss = _ce(text_logits[:, :-1], tokens[:, 1:])
+    if targets is None:
+        text_logits, targets = text_logits[:, :-1], tokens[:, 1:]
+    loss = _ce(text_logits, targets)
     metrics = {"ce": loss}
     if "moe_aux" in aux:
         loss = loss + aux["moe_aux"]
         metrics["moe_aux"] = aux["moe_aux"]
+    if "moe_picks" in aux:
+        metrics["moe_picks"] = aux["moe_picks"]
     if "mtp_logits" in aux:
-        mtp = aux["mtp_logits"][:, P:, :]
-        mtp_loss = _ce(mtp[:, :-2], tokens[:, 2:])
+        mtp = aux["mtp_logits"][:, P:P + targets.shape[1] - 1, :]
+        mtp_loss = _ce(mtp, targets[:, 1:])
         loss = loss + 0.3 * mtp_loss
         metrics["mtp"] = mtp_loss
     metrics["loss"] = loss

@@ -202,3 +202,125 @@ def test_spaceify_composition():
     isl = spaceify(FedProxSat(), intracc=True, isl=True, max_hops=2)
     assert isl.name == "fedprox_intracc_isl"
     assert isl.isl and isl.selector.max_hops == 2
+
+
+# ----------------------------------------------------- streamed rounds --
+# A round whose clients' model copies do not fit the device together is
+# trained one client after another in one program, each return folded
+# into one accumulator (`ConstellationSim._stream_clients`). On the CPU
+# every round fits, so the tests stream rounds themselves.
+def _femnist_sim(alg, **kw):
+    from repro.sim import ConstellationSim, SimConfig
+    return ConstellationSim(
+        WalkerStar(2, 5), station_subnetwork(13), ALGORITHMS[alg],
+        workload="femnist_mlp",
+        cfg=SimConfig(max_rounds=3, horizon_s=2 * 86400.0,
+                      clients_per_round=10, eval_every=1, max_steps=16,
+                      seed=7, **kw))
+
+
+def _stream(monkeypatch):
+    from repro.sim import engine
+    monkeypatch.setattr(engine, "copies_fit", lambda *a: False)
+
+
+@pytest.mark.parametrize("alg", ["fedavg", "fedavg_sched", "fedprox"])
+@pytest.mark.parametrize("sync", [False, True])
+def test_streamed_round_matches_the_stacked_aggregate(alg, sync,
+                                                       monkeypatch):
+    """Folding the clients one after another gives the stacked round's
+    weighted average, with the same records, whether the tracer syncs
+    or not; one `sim.client` span a round, carrying its clients and
+    executed steps."""
+    from repro import obs
+    stacked = _femnist_sim(alg).run()
+    _stream(monkeypatch)
+    with obs.tracing(sync=sync) as tracer:
+        streamed = _femnist_sim(alg).run()
+    assert [r.participants for r in streamed.rounds] == \
+        [r.participants for r in stacked.rounds]
+    for a, b in zip(jax.tree.leaves(streamed.final_params),
+                    jax.tree.leaves(stacked.final_params), strict=True):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    rounds = [s for s in tracer.events if s["name"] == "sim.client"]
+    assert [g["args"]["clients"] for g in rounds] == [
+        len(r.participants) for r in streamed.rounds]
+    assert all(g["args"]["steps"] % g["args"]["clients"] == 0
+               for g in rounds)
+    assert not any(s["name"] == "sim.aggregate" for s in tracer.events)
+
+
+# sha256 of the final parameters and the accuracy curve of `_femnist_sim`
+# runs, as the stacked host path computed them before rounds could be
+# streamed (CPU backend).
+FEMNIST_HOST_DIGESTS = {
+    "fedavg": "1de07b72bc75884c3265cd5411980132f96aee6a4c0d5b6f271107e0dba40c65",
+    "fedavg_sched": "c5d0559a00958b797e4429fa440a258663fb59c54756361db0c3f91e6784c3db",
+    "fedprox": "28ab603233e83e279fcfc284ae29afd37f7357a678f050f98194ac6b06a1f945",
+    "fedbuff": "90b1f2b6ed1a0e71d64e8c76f20cd4df50a8bda44f1bc34efb5745e6bd09ea16",
+}
+
+
+@pytest.mark.parametrize("alg", sorted(FEMNIST_HOST_DIGESTS))
+def test_femnist_host_path_keeps_its_result_bit_for_bit(alg):
+    import hashlib
+    res = _femnist_sim(alg).run()
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(res.final_params):
+        h.update(np.ascontiguousarray(leaf).tobytes())
+    h.update(repr([a for _, _, a in res.accuracy_curve]).encode())
+    assert h.hexdigest() == FEMNIST_HOST_DIGESTS[alg]
+
+
+def test_streaming_refuses_what_it_does_not_fold(monkeypatch):
+    """Buffered rounds anchored on older versions, and a strategy with an
+    aggregate of its own, are refused when their round is streamed."""
+    _stream(monkeypatch)
+    with pytest.raises(ValueError, match="anchored"):
+        _femnist_sim("fedbuff").run()
+
+    class Median(FedAvgSat):
+        def aggregate(self, global_params, client_params, weights,
+                      staleness):
+            return jax.tree.map(lambda x: jnp.median(x, axis=0),
+                                client_params)
+    with pytest.raises(ValueError, match="weighted average"):
+        from repro.sim import ConstellationSim, SimConfig
+        ConstellationSim(
+            WalkerStar(2, 5), station_subnetwork(13),
+            spaceify(Median(), name="median"), workload="femnist_mlp",
+            cfg=SimConfig(max_rounds=1, horizon_s=2 * 86400.0,
+                          max_steps=4)).run()
+
+
+@pytest.mark.parametrize("limit,fits", [
+    (None, True), (0, True), (20_000_000, False), (100_000_000, True),
+    (64_000_000, True), (63_999_999, False)])
+def test_copies_fit_follows_the_device_memory(limit, fits):
+    """Every round fits where the device reports no limit; otherwise the
+    global model, the result and three copies a client have to fit in
+    half the device."""
+    from repro.sim import engine
+    params = {"w": np.zeros((250_000,), np.float32)}       # 1 MB
+    assert engine.copies_fit(params, 10, limit) is fits
+
+
+def test_mesh_and_batched_refuse_a_loss_with_statistics():
+    from repro.core.workload import lm_workload
+    from repro.models.lm.config import ModelConfig, MoEConfig
+    from repro.sim import BatchedSweep, ConstellationSim, SimConfig
+    cfg = ModelConfig(name="moe", arch_type="moe", n_layers=1, d_model=16,
+                      n_heads=2, n_kv_heads=2, d_ff=32, vocab_size=32,
+                      moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=8),
+                      dtype="float32")
+    wl = lm_workload(cfg, name="moe_stats", seq_len=8,
+                     with_routing_stats=True)
+    kw = dict(workload=wl, cfg=SimConfig(max_rounds=1,
+                                         horizon_s=86400.0))
+    with pytest.raises(ValueError, match="statistics"):
+        ConstellationSim(WalkerStar(2, 5), station_subnetwork(3),
+                         ALGORITHMS["fedavg"], execution="mesh", **kw)
+    sim = ConstellationSim(WalkerStar(2, 5), station_subnetwork(3),
+                           ALGORITHMS["fedavg"], **kw)
+    with pytest.raises(ValueError, match="statistics"):
+        BatchedSweep([sim])

@@ -816,3 +816,88 @@ def test_traced_jit_call_reads_the_cache_only_while_tracing():
         with obs.span("sim.client_train") as sp:
             traced_jit_call(sp, Probe(), jnp.ones(3))
     assert len(reads) == 2 and t.events[0]["args"]["jit_compile"]
+
+
+# ------------------------------------------------------ routing counters --
+# An expert layer holding 8 of 64 experts, whose selection bias sends
+# every token to experts 0-5: each live local step puts T = batch x
+# seq_len picks on each of those six in every MoE layer, and none on 6, 7.
+MOE_LAYERS, SEQ, BATCH = 2, 8, 2
+
+
+def _routed_sim(**cfg_kw):
+    import dataclasses
+    from repro.core import ALGORITHMS
+    from repro.core.workload import lm_workload
+    from repro.models.lm.config import MLAConfig, ModelConfig, MoEConfig, \
+        Segment
+    from repro.orbits import WalkerStar, station_subnetwork
+    from repro.sim import ConstellationSim, SimConfig
+    cfg = ModelConfig(
+        name="routed", arch_type="moe", n_layers=1 + MOE_LAYERS, d_model=16,
+        n_heads=2, n_kv_heads=2, d_ff=32, vocab_size=64,
+        segments=(Segment("attn", 1), Segment("moe", MOE_LAYERS)),
+        moe=MoEConfig(n_experts=64, top_k=6, d_ff_expert=8, n_shared=2,
+                      scoring="sigmoid", routed_scale=2.446,
+                      held=tuple(range(8))),
+        mla=MLAConfig(q_lora_rank=None, kv_lora_rank=8, rope_head_dim=4,
+                      nope_head_dim=8, v_head_dim=8), dtype="float32")
+    wl = lm_workload(cfg, name="routed", seq_len=SEQ,
+                     with_routing_stats=True)
+
+    base = wl.init_fn
+
+    def init(rng):
+        p = base(rng)
+        p["segments"][1]["moe"]["router_bias"] = jnp.zeros(
+            (MOE_LAYERS, 64)).at[:, :6].set(100.0)
+        return p
+    wl = dataclasses.replace(wl, init_fn=init)
+    return ConstellationSim(
+        WalkerStar(2, 5), station_subnetwork(13), ALGORITHMS["fedavg"],
+        workload=wl, cfg=SimConfig(max_rounds=2, horizon_s=2 * 86400.0,
+                                   clients_per_round=4, batch_size=BATCH,
+                                   max_steps=4, eval_every=5, **cfg_kw))
+
+
+@pytest.mark.parametrize("streamed", [False, True])
+def test_routing_counters_read_the_hand_counted_picks(streamed, monkeypatch):
+    """Stacked or streamed, the counters are the hand count over the
+    live local steps of every client, and the run span carries them."""
+    from repro.sim import engine
+    if streamed:
+        monkeypatch.setattr(engine, "copies_fit", lambda *a: False)
+    sim = _routed_sim()
+    with obs.tracing(sync=True) as t:
+        res = sim.run()
+    steps = sum(sim._steps_for(k, e) for r in res.rounds
+                for k, e in zip(r.participants, r.epochs))
+    tokens = BATCH * SEQ
+    assert res.rounds and steps > 0
+    assert t.counters["moe.local_picks"] == steps * MOE_LAYERS * 6 * tokens
+    assert t.counters["moe.local_picks_max"] == steps * MOE_LAYERS * tokens
+    assert t.counters["moe.local_picks_mean"] == \
+        steps * MOE_LAYERS * 6 * tokens / 8
+    assert t.counters["moe.dropped"] == 0
+    run = next(s for s in t.events if s["name"] == "sim.run")
+    assert run["args"]["local_picks"] == t.counters["moe.local_picks"]
+    assert run["args"]["local_picks_max"] == \
+        t.counters["moe.local_picks_max"]
+    assert run["args"]["local_picks_mean"] == \
+        t.counters["moe.local_picks_mean"]
+
+
+def test_routing_counters_only_under_a_syncing_tracer():
+    """Untraced, nothing is counted or read back; a tracer that does not
+    sync counts no picks and reads nothing back for them."""
+    obs.disable()
+    plain = _routed_sim().run()
+    with obs.tracing(sync=False) as t:
+        quiet = _routed_sim().run()
+    assert not any(k.startswith("moe.") for k in t.counters)
+    with obs.tracing(sync=True) as t_sync:
+        _routed_sim().run()
+    assert t_sync.counters["sim.host_syncs"] > t.counters["sim.host_syncs"]
+    for a, b in zip(jax.tree.leaves(plain.final_params),
+                    jax.tree.leaves(quiet.final_params)):
+        np.testing.assert_array_equal(a, b)
