@@ -35,7 +35,7 @@ accuracy, round duration, and per-satellite idle time.
 overridable per run with `ConstellationSim(..., execution=...)`):
 
   * "host" — the reference path: one jitted vmap over stacked clients,
-    then `Strategy.aggregate` as a host-side weighted reduction; where
+    then `Strategy.aggregate` as one jitted program per client count; where
     the round's model copies do not fit the device together
     (`copies_fit`), one program trains its clients one after another and
     folds each return into one donated accumulator (`_stream_clients`);
@@ -451,6 +451,7 @@ class ConstellationSim:
             # Jitted updaters are built lazily per power-of-two step bound so
             # a 45-step FedAvg round never pays for the 128-step worst case.
             self._updaters: dict[tuple, object] = {}
+            self._agg = None          # `_aggregator`, built on first use
             # Mesh-path caches: one client mesh per pod-axis size, one
             # jitted collective round step per (step bound, axis size).
             self._meshes: dict[int, object] = {}
@@ -464,6 +465,17 @@ class ConstellationSim:
                 batch_size=self.cfg.batch_size, max_steps=bound,
                 anchored=anchored, has_aux=self.workload.loss_has_aux))
         return self._updaters[key]
+
+    def _aggregator(self):
+        """Jitted: the strategy's `aggregate`, one program per client
+        count. Traced for this sim alone, so the aggregation functions
+        are read as they stand when it first aggregates. It wraps a
+        fresh function: jit keys a bound method's trace by equality, and
+        sims share their strategy."""
+        if self._agg is None:
+            strategy = self.alg.strategy
+            self._agg = jax.jit(lambda *args: strategy.aggregate(*args))
+        return self._agg
 
     def _start(self, params, x, y, stacked=None):
         """The vmapped update's start state for the clients with shards
@@ -733,13 +745,10 @@ class ConstellationSim:
                 count("comms.codec_error", float(np.sqrt(to_host(err))))
             stacked = decoded
         with span("sim.aggregate", strategy=self.alg.strategy.name,
-                  clients=len(ks)):
-            out = self.alg.strategy.aggregate(
-                global_params, stacked, to_device(weights),
-                to_device(staleness))
-            if syncing():
-                jax.block_until_ready(out)
-        return out
+                  clients=len(ks)) as sp:
+            return traced_jit_call(sp, self._aggregator(), global_params,
+                                   stacked, to_device(weights),
+                                   to_device(staleness))
 
     def _finish_round(self, rounds: list[RoundRecord], curve: list,
                       global_params, *, t_start: float, t_end: float,

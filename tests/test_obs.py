@@ -431,6 +431,55 @@ def test_mesh_fedbuff_flags_its_retraces():
     assert sum(flags) == compiles > len(sim._mesh_steps)
 
 
+@pytest.mark.parametrize("alg,counts", [
+    ("ground_assisted", 2),    # a station's visit: rounds of 1 or 2 clients
+    ("fedbuff", 1),
+])
+def test_aggregate_compiles_once_per_client_count(alg, counts):
+    """The host loop's server update is one jitted program: each
+    distinct client count compiles it once, on its first update, and a
+    second run of the same sim compiles nothing."""
+    sim = _train_sim(alg=alg, max_rounds=6)
+    with obs.tracing() as t:
+        sim.run()
+        first = [ev["args"] for ev in t.events
+                 if ev["name"] == "sim.aggregate"]
+        compiles = t.counter("sim.jit_compiles")
+        sim.run()
+        second = [ev["args"] for ev in t.events
+                  if ev["name"] == "sim.aggregate"][len(first):]
+        assert t.counter("sim.jit_compiles") == compiles
+    assert first and len(second) == len(first)
+    seen = set()
+    for args in first:
+        assert args["jit_compile"] is (args["clients"] not in seen)
+        seen.add(args["clients"])
+    assert not any(args["jit_compile"] for args in second)
+    assert sim._aggregator()._cache_size() == len(seen) == counts
+
+
+def test_each_sim_traces_its_own_aggregate(monkeypatch):
+    """A fault planted in the aggregation functions reaches a sim built
+    after it, and a sim built after it is undone is bitwise the first:
+    no trace is shared across sims. Every sim stays alive, as in one
+    process that calibrates, so no cache entry dies with its sim."""
+    from bench.tests import small
+
+    sims = [_train_sim(max_rounds=2)]
+    sound = sims[0].run().final_params
+    small.half_clients(monkeypatch)
+    sims.append(_train_sim(max_rounds=2))
+    faulted = sims[1].run().final_params
+    monkeypatch.undo()
+    sims.append(_train_sim(max_rounds=2))
+    again = sims[2].run().final_params
+    assert any(not np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(faulted), jax.tree.leaves(sound), strict=True))
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(sound),
+                    strict=True):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_spans_on_the_profiler_host_plane(tmp_path):
     """Under the profiler, a traced run's spans are annotations on the
     host plane, nested there as the tracer recorded them."""
